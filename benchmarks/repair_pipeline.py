@@ -8,10 +8,12 @@ scrubbed-bytes/step for
   * the mesh-native **compiled** path (`ApproxSpace` dispatching one cached
     donated executable per state layout),
 
-on this process's devices and — via a subprocess with
+on this process's devices and — via a CPU-pinned subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — on 8 fake host
 devices with the state FSDP-sharded, where the executable repairs
-shard-locally.  Acceptance: compiled ≤ eager at smoke shapes (asserted).
+shard-locally.  Every section records its ``backend``; the timings are
+host-clock numbers of that backend, never device metrics.  Acceptance:
+compiled ≤ eager at smoke shapes (asserted).
 
 CSV: ``name,us_per_call,scrubbed_mb_per_step``; ``main(out=...)`` writes the
 full record to JSON (``benchmarks/run.py --out BENCH_repair.json``).
@@ -44,7 +46,9 @@ def _sharded(tree):
     """FSDP-style placement over all local devices (row-sharded matrices)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((jax.device_count(),), ("data",))
 
     def put(leaf):
         spec = P("data") if (
@@ -109,6 +113,7 @@ def measure(n: int, reps: int, *, shard: bool = False) -> Dict[str, Any]:
 
     bytes0 = space.scrubbed_bytes
     res = {
+        "backend": jax.default_backend(),
         "devices": jax.device_count(),
         "placement": space.plan_for(tree).placement,
         "shape": [n, n],
@@ -125,8 +130,14 @@ def measure(n: int, reps: int, *, shard: bool = False) -> Dict[str, Any]:
 
 
 def _measure_subprocess(n: int, reps: int, devices: int) -> Optional[Dict]:
-    """Re-run this module under ``devices`` fake host devices."""
+    """Re-run this module under ``devices`` fake host devices.
+
+    The child is pinned to the CPU backend: its fake devices are a CPU
+    rehearsal of the sharded placement, and a parent that has touched JAX
+    holds the accelerator, so a child reaching for it would contend for
+    the chip.  Its section records ``"backend": "cpu"``."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices} "
         + env.get("XLA_FLAGS", "")
@@ -166,10 +177,11 @@ def main(smoke: bool = False, out: Optional[str] = None) -> Dict[str, Any]:
 
     for name, sec in record["sections"].items():
         mb = sec["scrubbed_bytes_per_step"] / 1e6
+        label = f"{name}[{sec['backend']}]"
         for kind in ("scrub", "inject"):
-            print(f"{name}/eager_{kind},{sec[f'eager_{kind}_us']:.1f},{mb:.3f}")
+            print(f"{label}/eager_{kind},{sec[f'eager_{kind}_us']:.1f},{mb:.3f}")
             print(
-                f"{name}/compiled_{kind},"
+                f"{label}/compiled_{kind},"
                 f"{sec[f'compiled_{kind}_us']:.1f},{mb:.3f}"
             )
 

@@ -55,8 +55,8 @@ import jax
 
 from repro.configs import get_config
 from repro.models import build_model
-from repro.runtime import ApproxConfig
-from repro.serving import Engine, ServingConfig
+from repro.runtime import ApproxConfig, ApproxSpace
+from repro.serving import Engine, ServingConfig, engine_space
 
 # single-bit flips on healthy f32 lanes only rarely land in the exponent's
 # fatal pattern, so the BER points sit high enough that every run fires
@@ -115,6 +115,11 @@ def run(smoke: bool = False):
                     paged_prefill=paged_prefill, split_k=split_k,
                     ber=ber, sweep_interval=16, sweep_pages=2, seed=7,
                 ),
+                # the NaN/Inf rule: the engine's default range guard also
+                # counts flips of the padding K/V that empty decode slots
+                # write into the null page on the fused path only, which
+                # would charge the fused arms an extra null-page scrub
+                space=ApproxSpace(engine_space(model).config, max_magnitude=None),
             )
             if paged_decode == "auto":
                 assert engine.paged_plan is not None, (

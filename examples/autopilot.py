@@ -22,9 +22,11 @@ import dataclasses
 
 from repro.autopilot import run_campaign, solve_frontier
 from repro.configs import get_preset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # -- 1. the profiling campaign ---------------------------------------
     # the transformer preset: a tiny qwen2 with two region groups.  Keep
     # the sweep short for the demo — two refresh points, six decode steps.

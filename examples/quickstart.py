@@ -14,10 +14,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import ApproxConfig, ApproxSpace
 
 
 def main():
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
     k1, k2, k3 = jax.random.split(key, 3)
     n = 512

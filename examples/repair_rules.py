@@ -13,12 +13,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import stats as stats_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import (
     ApproxConfig, ApproxSpace, Detector, RepairRule, RuleSet,
 )
 
 
 def main():
+    enable_compile_cache()
     rules = RuleSet((
         # optimizer moments: a flipped high exponent bit yields ~1e38 — a
         # legal float that destroys training.  Range-guard + tile-mean fill.

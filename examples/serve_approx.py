@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import build_serve_step, serve_space
 from repro.models import build_model
 from repro.runtime import ApproxConfig
@@ -27,6 +28,7 @@ from repro.core import stats as stats_lib
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--batch", type=int, default=4)

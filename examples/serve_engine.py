@@ -20,12 +20,14 @@ import time
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.runtime import ApproxConfig
 from repro.serving import Engine, ServingConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--requests", type=int, default=8)

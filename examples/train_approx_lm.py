@@ -24,6 +24,7 @@ import jax
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import make_optimizer, train_loop
 from repro.models import build_model
 from repro.runtime import ApproxConfig, ApproxSpace
@@ -57,6 +58,7 @@ def build_100m(arch: str, repair_mode: str) -> "ArchConfig":
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--steps", type=int, default=300)

@@ -89,11 +89,13 @@ class ArchConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     def reduced(self) -> "ArchConfig":
-        """Same family, laptop-scale — used by per-arch smoke tests.
+        """Same family, laptop-scale — used by per-arch CPU smoke tests.
 
         f32 storage: the CPU backend cannot *execute* some bf16 batched dots
-        (DotThunk); full-size bf16 configs are only ever lowered (dry-run),
-        never executed on CPU."""
+        (DotThunk), so full-size bf16 configs never execute on the CPU.
+        They run on a TPU (``chip_smoke.py`` serves full-size qwen2-1.5b
+        through the Engine) and are compiled for a described TPU by
+        ``tests/test_tpu_compile.py`` and lowered by ``launch/dryrun.py``."""
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",

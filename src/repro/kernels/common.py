@@ -27,6 +27,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from ..core import detect, rules as rules_lib
 
@@ -102,14 +103,30 @@ def detector_operand(
     return base.at[6].set(jnp.asarray(n_valid_rows, jnp.int32))
 
 
+def consts_row(ref, row: Optional[int] = None) -> Tuple[jax.Array, ...]:
+    """The eight detector constants of a scalar-prefetch ref (row ``row``
+    of an int32[R, 8] operand, or the whole int32[8] one), loaded one
+    scalar at a time: on TPU the operand lives in SMEM, which serves
+    scalar loads only."""
+    if row is None:
+        return tuple(ref[i] for i in range(8))
+    return tuple(ref[row, i] for i in range(8))
+
+
+def zero_counts(ref, n: int) -> None:
+    """Zero an int32[n] SMEM counter output one scalar store at a time."""
+    for i in range(n):
+        ref[i] = jnp.int32(0)
+
+
 def masks_from_consts(
-    bits: jax.Array, consts: jax.Array
+    bits: jax.Array, consts
 ) -> Tuple[jax.Array, jax.Array]:
     """(nan_mask, inf_mask) of a tile's integer bit view, driven by the
-    detector-constants operand.  Mirrors ``Detector.masks`` exactly (same
-    bucket rules, so kernel counters and the jnp oracle agree): custom bit
-    patterns land in the NaN bucket; the range guard owns the non-NaN
-    bucket when enabled (it subsumes ±Inf)."""
+    detector constants (``consts_row`` scalars).  Mirrors
+    ``Detector.masks`` exactly (same bucket rules, so kernel counters and
+    the jnp oracle agree): custom bit patterns land in the NaN bucket; the
+    range guard owns the non-NaN bucket when enabled (it subsumes ±Inf)."""
     u = lambda i: consts[i].astype(jnp.uint32)                       # noqa: E731
     b = bits.astype(jnp.uint32)
     exp_mask, man_mask, flags = u(0), u(1), consts[2]
@@ -166,13 +183,13 @@ def repair_tile(
     policy: str,
     constant: float = 0.0,
     include_inf: bool = True,
-    consts: Optional[jax.Array] = None,
+    consts=None,
     count_mask: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Repair a VMEM tile.  Returns (repaired, nan_count, inf_count) where the
     counts are int32 scalars for the event counters (Table 3 analogue).
 
-    With ``consts`` (the detector-constants scalar operand) detection is
+    With ``consts`` (the detector constants, ``consts_row``) detection is
     data-driven — NaN/Inf/range/bit-pattern enables read from SMEM; the bare
     ``include_inf`` form keeps the legacy static NaN(+Inf) pattern.
     ``count_mask`` (bool, tile-shaped) restricts the COUNTS to its True
@@ -210,3 +227,11 @@ def repair_tile(
 def default_interpret() -> bool:
     """Run kernels in interpret mode unless we are actually on TPU."""
     return jax.default_backend() != "tpu"
+
+
+def smem_spec():
+    """Whole-array SMEM block: the counter outputs every grid step
+    accumulates into with scalar stores (VMEM takes no scalar stores)."""
+    from jax.experimental.pallas import tpu as pltpu  # local: CPU-safe import
+
+    return pl.BlockSpec(memory_space=pltpu.SMEM)

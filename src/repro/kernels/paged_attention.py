@@ -66,6 +66,11 @@ NAN_K, INF_K, EV_K, NAN_V, INF_V, EV_V, EV_TOTAL = range(7)
 # for that operand), so the default cannot be None.
 DEFAULT_DETECTOR = "default"
 
+# scoped VMEM of the chunked-q prefill kernel: its (C*H, pg) score tile
+# spills registers (~11 MB at C = 256, H = 12), which overflows the default
+# scoped limit once the kernel runs under shard_map
+_PREFILL_VMEM_BYTES = 32 << 20
+
 # per-slot chunk-start sentinel for the sharded prefill walk: a slot whose
 # q_start carries this value belongs to another device's shard — every
 # causal comparison fails (tq is hugely negative) and the count gate is off
@@ -73,7 +78,7 @@ NO_SLOT = -(1 << 30)
 
 
 def _repair_and_count(
-    consts_ref, k_ref, v_ref, slot_ref, counts_ref,
+    consts_ref, k_ref, v_ref, slot_ref, counts_ref, slot,
     *, policy_k: str, constant_k: float, policy_v: str, constant_v: float,
     gate=None,
 ):
@@ -89,16 +94,20 @@ def _repair_and_count(
     the pages of its shard — non-owned slots are remapped to a local row
     whose faults belong to another device, so their detections must not be
     reported here (the VMEM repair itself is harmless: the slot's scores
-    are fully masked).  Each page is thus counted by exactly one device."""
+    are fully masked).  Each page is thus counted by exactly one device.
+
+    ``slot`` is the ``(b, j)`` block-table slot this grid step visits.  The
+    constants, the counters and the slot counts all live in SMEM, so every
+    access is one scalar load or store."""
     if gate is None:
         gate = jnp.int32(1)
     k_fixed, nan_k, inf_k = common.repair_tile(
         k_ref[0, 0], policy=policy_k, constant=constant_k,
-        consts=consts_ref[0],
+        consts=common.consts_row(consts_ref, 0),
     )
     v_fixed, nan_v, inf_v = common.repair_tile(
         v_ref[0, 0], policy=policy_v, constant=constant_v,
-        consts=consts_ref[1],
+        consts=common.consts_row(consts_ref, 1),
     )
     ev_k = ((nan_k + inf_k) > 0).astype(jnp.int32)
     ev_v = ((nan_v + inf_v) > 0).astype(jnp.int32)
@@ -109,7 +118,7 @@ def _repair_and_count(
     counts_ref[INF_V] += gate * inf_v
     counts_ref[EV_V] += gate * ev_v
     counts_ref[EV_TOTAL] += gate * ((ev_k + ev_v) > 0).astype(jnp.int32)
-    slot_ref[0, 0] = gate * (nan_k + inf_k + nan_v + inf_v)
+    slot_ref[slot] = gate * (nan_k + inf_k + nan_v + inf_v)
     return k_fixed, v_fixed
 
 
@@ -161,7 +170,7 @@ def _paged_kernel(
 
     @pl.when(step == 0)
     def _init_counts():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        common.zero_counts(counts_ref, 8)
 
     @pl.when(j == 0)
     def _init_state():
@@ -170,7 +179,7 @@ def _paged_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     k_fixed, v_fixed = _repair_and_count(
-        consts_ref, k_ref, v_ref, slot_ref, counts_ref,
+        consts_ref, k_ref, v_ref, slot_ref, counts_ref, (b, j),
         policy_k=policy_k, constant_k=constant_k,
         policy_v=policy_v, constant_v=constant_v,
     )
@@ -289,8 +298,8 @@ def paged_attention_raw(
         ],
         out_specs=[
             pl.BlockSpec((1, H, Dh), lambda b, j, c, bt, pos, lay: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j, c, bt, pos, lay: (b, j)),
-            pl.BlockSpec((8,), lambda b, j, c, bt, pos, lay: (0,)),
+            common.smem_spec(),     # slot counts (B, M)
+            common.smem_spec(),     # counts int32[8]
         ],
         scratch_shapes=[
             pltpu.VMEM((H, Dh), jnp.float32),
@@ -376,7 +385,7 @@ def _paged_prefill_kernel(
 
     @pl.when(step == 0)
     def _init_counts():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        common.zero_counts(counts_ref, 8)
 
     @pl.when(j == 0)
     def _init_state():
@@ -389,7 +398,7 @@ def _paged_prefill_kernel(
     # which kills every causal comparison below and gates the counts off
     qs = qstart_ref[b, j]
     k_fixed, v_fixed = _repair_and_count(
-        consts_ref, k_ref, v_ref, slot_ref, counts_ref,
+        consts_ref, k_ref, v_ref, slot_ref, counts_ref, (b, j),
         policy_k=policy_k, constant_k=constant_k,
         policy_v=policy_v, constant_v=constant_v,
         gate=(qs >= 0).astype(jnp.int32),
@@ -445,8 +454,8 @@ def _paged_prefill_kernel(
     def _flush():
         # raw partials — normalization happens in the caller / LSE merge
         o_ref[0] = acc_ref[...].reshape(nc, n_kv * group, Dh)
-        mo_ref[0] = m_ref[:, 0]
-        lo_ref[0] = l_ref[:, 0]
+        mo_ref[0, 0] = m_ref[:, 0]
+        lo_ref[0, 0] = l_ref[:, 0]
 
 
 def _prefill_partials(
@@ -487,10 +496,12 @@ def _prefill_partials(
         ],
         out_specs=[
             pl.BlockSpec((1, C, H, Dh), lambda b, j, c, bt, qs, lay: (b, 0, 0, 0)),
-            pl.BlockSpec((1, C * H), lambda b, j, c, bt, qs, lay: (b, 0)),
-            pl.BlockSpec((1, C * H), lambda b, j, c, bt, qs, lay: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, j, c, bt, qs, lay: (b, j)),
-            pl.BlockSpec((8,), lambda b, j, c, bt, qs, lay: (0,)),
+            # (B, 1, C*H): a unit second-minor axis keeps the block's last
+            # two dims equal to the array's, as the TPU tiling requires
+            pl.BlockSpec((1, 1, C * H), lambda b, j, c, bt, qs, lay: (b, 0, 0)),
+            pl.BlockSpec((1, 1, C * H), lambda b, j, c, bt, qs, lay: (b, 0, 0)),
+            common.smem_spec(),     # slot counts (B, M)
+            common.smem_spec(),     # counts int32[8]
         ],
         scratch_shapes=[
             pltpu.VMEM((C * H, Dh), jnp.float32),
@@ -498,7 +509,7 @@ def _prefill_partials(
             pltpu.VMEM((C * H, 128), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    acc, m, l, slot_counts, counts = pl.pallas_call(
         functools.partial(
             _paged_prefill_kernel,
             sm_scale=sm_scale,
@@ -515,11 +526,14 @@ def _prefill_partials(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, C, H, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, C * H), jnp.float32),
-            jax.ShapeDtypeStruct((B, C * H), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, C * H), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, C * H), jnp.float32),
             jax.ShapeDtypeStruct((B, M), jnp.int32),
             jax.ShapeDtypeStruct((8,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES
+        ),
         interpret=interpret,
     )(
         consts,
@@ -528,6 +542,7 @@ def _prefill_partials(
         jnp.asarray(layer, jnp.int32).reshape(1),
         q, k_pages, v_pages,
     )
+    return acc, m[:, 0], l[:, 0], slot_counts, counts
 
 
 def _prefill_normalize(out_dtype, acc, l):
@@ -647,7 +662,7 @@ def _paged_splitk_kernel(
 
     @pl.when(step == 0)
     def _init_counts():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        common.zero_counts(counts_ref, 8)
 
     @pl.when(jj == 0)
     def _init_state():
@@ -660,7 +675,7 @@ def _paged_splitk_kernel(
     # every key position fails `t <= bound` and the count gate is off
     bound = pos_ref[b, g * ns + jj]
     k_fixed, v_fixed = _repair_and_count(
-        consts_ref, k_ref, v_ref, slot_ref, counts_ref,
+        consts_ref, k_ref, v_ref, slot_ref, counts_ref, (b, g * ns + jj),
         policy_k=policy_k, constant_k=constant_k,
         policy_v=policy_v, constant_v=constant_v,
         gate=(bound >= 0).astype(jnp.int32),
@@ -707,8 +722,8 @@ def _paged_splitk_kernel(
     def _flush():
         # raw partials — normalization happens in the LSE merge stage
         o_ref[0, 0] = acc_ref[...]
-        mo_ref[0, 0] = m_ref[:, 0]
-        lo_ref[0, 0] = l_ref[:, 0]
+        mo_ref[0, 0, 0] = m_ref[:, 0]
+        lo_ref[0, 0, 0] = l_ref[:, 0]
 
 
 def _splitk_partials(
@@ -760,12 +775,16 @@ def _splitk_partials(
             pl.BlockSpec(
                 (1, 1, H, Dh), lambda b, g, jj, c, bt, pos, lay: (b, g, 0, 0)
             ),
-            pl.BlockSpec((1, 1, H), lambda b, g, jj, c, bt, pos, lay: (b, g, 0)),
-            pl.BlockSpec((1, 1, H), lambda b, g, jj, c, bt, pos, lay: (b, g, 0)),
+            # (B, splits, 1, H): a unit second-minor axis keeps the
+            # block's last two dims equal to the array's (TPU tiling)
             pl.BlockSpec(
-                (1, 1), lambda b, g, jj, c, bt, pos, lay: (b, g * ns + jj)
+                (1, 1, 1, H), lambda b, g, jj, c, bt, pos, lay: (b, g, 0, 0)
             ),
-            pl.BlockSpec((8,), lambda b, g, jj, c, bt, pos, lay: (0,)),
+            pl.BlockSpec(
+                (1, 1, 1, H), lambda b, g, jj, c, bt, pos, lay: (b, g, 0, 0)
+            ),
+            common.smem_spec(),     # slot counts (B, M)
+            common.smem_spec(),     # counts int32[8]
         ],
         scratch_shapes=[
             pltpu.VMEM((H, Dh), jnp.float32),
@@ -773,7 +792,7 @@ def _splitk_partials(
             pltpu.VMEM((H, 128), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o, m, l, slot_counts, counts = pl.pallas_call(
         functools.partial(
             _paged_splitk_kernel,
             sm_scale=sm_scale,
@@ -789,8 +808,8 @@ def _splitk_partials(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, splits, H, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, splits, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, splits, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, splits, 1, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, splits, 1, H), jnp.float32),
             jax.ShapeDtypeStruct((B, M), jnp.int32),
             jax.ShapeDtypeStruct((8,), jnp.int32),
         ],
@@ -802,6 +821,7 @@ def _splitk_partials(
         jnp.asarray(layer, jnp.int32).reshape(1),
         q, k_pages, v_pages,
     )
+    return o, m[:, :, 0], l[:, :, 0], slot_counts, counts
 
 
 @functools.partial(
@@ -905,13 +925,15 @@ def paged_attention_splitk(
 #   └──────────────────────┘       d1: slots with page ∈ [P/nd, …)  masked
 #                                   ⋮   (bound/-qstart sentinel, gate off)
 #   each device walks its OWN shard rows only → partials (acc, m, l)
-#   all_gather(device-major) → LSE merge;  psum(slot_counts, counts)
+#   all_gather → per-split merge over devices → LSE merge over splits;
+#   psum(slot_counts, counts)
 #
 # Every block-table slot is owned by exactly one device (the null page by
 # the device holding the pool's last row), so the psum'd integer counts are
 # bit-identical to the serial kernel's, and the merged output is
 # bit-identical to `paged_*_shard_ref` — the same partition computed shard
-# by shard on one device.
+# by shard on one device — and to the single-device kernel wherever no
+# split straddles a shard boundary.
 
 
 def _owned_remap(block_tables, lo, p_local):
@@ -923,20 +945,29 @@ def _owned_remap(block_tables, lo, p_local):
     return owned, jnp.where(owned, block_tables - lo, 0)
 
 
+def _shard_merge(out_dtype, o, m, l):
+    """Merge per-device partials laid out ``(B, nd, S, ...)``: each split's
+    device partials combine first, then the splits LSE-merge exactly as
+    on one device.  A split whose pages one device owns passes the first
+    stage unchanged (weight exp(0) = 1; the other devices' partials are
+    empty and weigh 0), so a walk whose splits never straddle a shard
+    boundary is bit-identical to the single-device kernel."""
+    m_s = jnp.max(m, axis=1)                                 # (B, S, H)
+    live = m > NEG_INF * 0.5
+    w = jnp.where(live, jnp.exp(m - m_s[:, None]), 0.0)      # (B, nd, S, H)
+    l_s = jnp.sum(w * l, axis=1)
+    o_s = jnp.sum(w[..., None] * o, axis=1)
+    return _lse_merge(out_dtype, o_s, m_s, l_s)
+
+
 def _device_major_merge(out_dtype, o, m, l, axis):
-    """all_gather each device's partials and LSE-merge them device-major:
-    device d's partial s lands at merge slot ``d * splits + s`` — the same
-    order `paged_*_shard_ref` concatenates, so parity is bitwise."""
-    B = o.shape[0]
-    o_all = jnp.moveaxis(jax.lax.all_gather(o, axis), 0, 1)
-    m_all = jnp.moveaxis(jax.lax.all_gather(m, axis), 0, 1)
-    l_all = jnp.moveaxis(jax.lax.all_gather(l, axis), 0, 1)
-    nd = o_all.shape[1]
-    s = o_all.shape[2]
-    o_all = o_all.reshape(B, nd * s, *o.shape[2:])
-    m_all = m_all.reshape(B, nd * s, m.shape[-1])
-    l_all = l_all.reshape(B, nd * s, l.shape[-1])
-    return _lse_merge(out_dtype, o_all, m_all, l_all)
+    """all_gather each device's partials — device d's partial s lands at
+    ``(b, d, s)``, the layout `paged_*_shard_ref` stacks — and merge them
+    with ``_shard_merge``, so parity with the oracle is bitwise."""
+    def gather(x):
+        return jnp.moveaxis(jax.lax.all_gather(x, axis), 0, 1)
+
+    return _shard_merge(out_dtype, gather(o), gather(m), gather(l))
 
 
 def paged_attention_sharded(
@@ -969,11 +1000,11 @@ def paged_attention_sharded(
     placeholder).  ``splits > 1`` composes split-K *within* each device's
     walk, yielding ``nd × splits`` partials.  Counts are psum'd (each slot
     counted exactly once, bit-identical to the serial kernel); the output
-    is the device-major LSE merge (bit-identical to
-    ``paged_attention_shard_ref``).  Returns the same triple as
-    ``paged_attention_raw``.
+    merges each split's device partials, then the splits (``_shard_merge``;
+    bit-identical to ``paged_attention_shard_ref``, and to the split-K
+    kernel when no split straddles a shard boundary).  Returns the same
+    triple as ``paged_attention_raw``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     if interpret is None:
@@ -1009,11 +1040,11 @@ def paged_attention_sharded(
 
     spec = PartitionSpec(axis)
     rep = PartitionSpec()
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep, spec, spec, rep, rep, rep, rep),
         out_specs=(rep, rep, rep),
-        check_rep=False,
+        check_vma=False,
     )(q, k_pages, v_pages, bt, pos, lay, consts)
 
 
@@ -1026,9 +1057,9 @@ def paged_attention_shard_ref(
     policy_k=None, constant_k=None, policy_v=None, constant_v=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Single-device oracle of ``paged_attention_sharded``: the identical
-    ownership partition and device-major merge, computed shard by shard on
-    one device.  The sharded entry must match this bit for bit — it is the
-    parity target of the multidev lane (the *serial* kernel differs in
+    ownership partition and merge, computed shard by shard on one device.
+    The sharded entry must match this bit for bit — it is the parity
+    target of the multidev lane (the *serial* kernel differs in
     accumulation grouping, so its float output is only allclose)."""
     if interpret is None:
         interpret = common.default_interpret()
@@ -1063,11 +1094,11 @@ def paged_attention_shard_ref(
         ls_.append(l)
         slot_tot = slot if slot_tot is None else slot_tot + slot
         counts_tot = counts if counts_tot is None else counts_tot + counts
-    out = _lse_merge(
+    out = _shard_merge(
         q.dtype,
-        jnp.concatenate(os_, axis=1),
-        jnp.concatenate(ms_, axis=1),
-        jnp.concatenate(ls_, axis=1),
+        jnp.stack(os_, axis=1),
+        jnp.stack(ms_, axis=1),
+        jnp.stack(ls_, axis=1),
     )
     return out, slot_tot, counts_tot
 
@@ -1098,10 +1129,10 @@ def paged_prefill_sharded(
     The sharded analogue of ``paged_prefill_raw``: non-owned block slots
     carry the ``NO_SLOT`` q_start sentinel (every causal comparison fails,
     counts gated), each device emits one unnormalized chunk partial, and
-    the device-major LSE merge normalizes — bit-identical to
-    ``paged_prefill_shard_ref``.
+    ``_shard_merge`` normalizes — bit-identical to
+    ``paged_prefill_shard_ref``, and to ``paged_prefill_raw`` when one
+    device owns every attended page.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     if interpret is None:
@@ -1143,11 +1174,11 @@ def paged_prefill_sharded(
 
     spec = PartitionSpec(axis)
     rep = PartitionSpec()
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep, spec, spec, rep, rep, rep, rep),
         out_specs=(rep, rep, rep),
-        check_rep=False,
+        check_vma=False,
     )(q, k_pages, v_pages, bt, qs, lay, consts)
 
 
@@ -1195,10 +1226,10 @@ def paged_prefill_shard_ref(
         ls_.append(l[:, None])
         slot_tot = slot if slot_tot is None else slot_tot + slot
         counts_tot = counts if counts_tot is None else counts_tot + counts
-    merged = _lse_merge(
+    merged = _shard_merge(
         q.dtype,
-        jnp.concatenate(os_, axis=1),
-        jnp.concatenate(ms_, axis=1),
-        jnp.concatenate(ls_, axis=1),
+        jnp.stack(os_, axis=1),
+        jnp.stack(ms_, axis=1),
+        jnp.stack(ls_, axis=1),
     )
     return merged.reshape(B, C, H, Dh), slot_tot, counts_tot

@@ -56,7 +56,7 @@ def _flash_kernel(
 
     @pl.when(step == 0)
     def _init_counts():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        common.zero_counts(counts_ref, 8)
 
     @pl.when(kj == 0)
     def _init_state():
@@ -75,11 +75,11 @@ def _flash_kernel(
         # ---- fused reactive repair of the cached K/V tiles ----
         k_fixed, nan_k, inf_k = common.repair_tile(
             k_ref[0, 0], policy=policy, constant=constant,
-            consts=consts_ref[0],
+            consts=common.consts_row(consts_ref, 0),
         )
         v_fixed, nan_v, inf_v = common.repair_tile(
             v_ref[0, 0], policy=policy, constant=constant,
-            consts=consts_ref[1],
+            consts=common.consts_row(consts_ref, 1),
         )
         ev_k = ((nan_k + inf_k) > 0).astype(jnp.int32)
         ev_v = ((nan_v + inf_v) > 0).astype(jnp.int32)
@@ -179,7 +179,7 @@ def flash_attention_raw(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, c: (b, h, i, 0)),
-            pl.BlockSpec((8,), lambda b, h, i, j, c: (0,)),
+            common.smem_spec(),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),

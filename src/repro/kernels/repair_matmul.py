@@ -13,7 +13,7 @@ tile the kernel already loaded**:
     the paper's SIGFPE *detection* step.
 
   * Event counters (the Table 3 analogue) accumulate per-operand NaN/Inf lane
-    counts and tile-visit events into a tiny VMEM-resident output.  A visit
+    counts and tile-visit events into a tiny SMEM-resident output.  A visit
     of a poisoned tile == one "trap".
 
   * **register mode** stops there: the stored buffer keeps its NaN, so every
@@ -69,7 +69,7 @@ def _mm_kernel(
 
     @pl.when(step == 0)
     def _init_counts():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        common.zero_counts(counts_ref, 8)
 
     @pl.when(k == 0)
     def _init_acc():
@@ -78,10 +78,12 @@ def _mm_kernel(
     # ---- fused reactive repair: operand tiles, pre-MXU ----
     # row 0: a's dtype constants; row 1: b's (operands may differ in dtype)
     a_fixed, nan_a, inf_a = common.repair_tile(
-        a_ref[...], policy=policy, constant=constant, consts=consts_ref[0]
+        a_ref[...], policy=policy, constant=constant,
+        consts=common.consts_row(consts_ref, 0),
     )
     b_fixed, nan_b, inf_b = common.repair_tile(
-        b_ref[...], policy=policy, constant=constant, consts=consts_ref[1]
+        b_ref[...], policy=policy, constant=constant,
+        consts=common.consts_row(consts_ref, 1),
     )
     ev_a = ((nan_a + inf_a) > 0).astype(jnp.int32)
     ev_b = ((nan_b + inf_b) > 0).astype(jnp.int32)
@@ -153,7 +155,7 @@ def repair_matmul_raw(
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, k, c: (i, j)),
-            pl.BlockSpec((8,), lambda i, j, k, c: (0,)),
+            common.smem_spec(),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )

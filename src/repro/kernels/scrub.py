@@ -18,9 +18,8 @@ the input buffer (``input_output_aliases``), so on TPU the scrub is in-place
 in HBM, exactly like the paper's repair of the faulting address.
 
 Outputs: (scrubbed, counts) with counts = int32[3] = [nan, inf, events]
-accumulated across all grid steps (constant index map — every grid step
-revisits the same counts block, which therefore lives in VMEM for the whole
-call).
+accumulated across all grid steps (a whole-array SMEM output — every grid
+step adds to the same three scalars, written back once at the end).
 """
 from __future__ import annotations
 
@@ -44,19 +43,20 @@ def _scrub_kernel(
 
     @pl.when(step == 0)
     def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        common.zero_counts(counts_ref, 3)
 
     tile = x_ref[...]
+    consts = common.consts_row(consts_ref)
     # consts[6] > 0: count-valid row bound — rows ≥ bound (the page scrub's
     # padding duplicates) are repaired like any other but masked out of the
     # lane counts, so padded and unpadded calls report identical stats
-    n_valid = consts_ref[6]
+    n_valid = consts[6]
     row_ids = pl.program_id(0) * tile.shape[0] + jax.lax.broadcasted_iota(
         jnp.int32, tile.shape, 0
     )
     count_mask = (n_valid == 0) | (row_ids < n_valid)
     fixed, n_nan, n_inf = common.repair_tile(
-        tile, policy=policy, constant=constant, consts=consts_ref[...],
+        tile, policy=policy, constant=constant, consts=consts,
         count_mask=count_mask,
     )
     out_ref[...] = fixed
@@ -128,7 +128,7 @@ def scrub(
         in_specs=[pl.BlockSpec((br, bc), lambda i, j, c: (i, j))],
         out_specs=[
             pl.BlockSpec((br, bc), lambda i, j, c: (i, j)),
-            pl.BlockSpec((3,), lambda i, j, c: (0,)),
+            common.smem_spec(),
         ],
     )
     out, counts = pl.pallas_call(
@@ -171,7 +171,6 @@ def scrub_sharded(
     tiles, not the global array's), the same way the fused kernels' event
     counts follow their block shapes.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if interpret is None:
@@ -196,9 +195,9 @@ def scrub_sharded(
             counts = jax.lax.psum(counts, axis_name=used)
         return fixed, counts
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(spec,), out_specs=(spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x)
 
 

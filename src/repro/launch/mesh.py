@@ -8,23 +8,37 @@ across pods — the slowest links carry only gradient all-reduces).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(
+    shape: Sequence[int], axes: Sequence[str], *, devices=None
+) -> Mesh:
+    """A mesh whose axes are all ``Auto``: the repo places arrays with
+    ``NamedSharding`` constraints and lets the partitioner propagate them
+    (``jax.make_mesh`` itself now defaults to ``Explicit`` axes, under
+    which sharded slicing, reshapes and scatters must name their output
+    sharding)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(AxisType.Auto,) * len(axes), devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: Optional[int] = None, model: int = 1) -> Mesh:
     """Mesh over whatever devices exist (tests / examples on CPU)."""
     n = jax.device_count()
     data = data if data is not None else n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # Hardware constants (TPU v5e) used by the roofline analysis.

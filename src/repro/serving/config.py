@@ -135,6 +135,13 @@ class ServingConfig:
                              (applied to the pool between engine steps;
                              0 disables injection)
       seed                   PRNG seed for injection + pool init
+
+    Observation:
+      record_logits          keep every generated token's readout logits
+                             (f32, one row of ``vocab``) in
+                             ``Engine.results[rid]["logits"]`` — for
+                             parity checks between serving paths.  Costs
+                             one extra (max_batch, vocab) readback per lane.
     """
 
     page_size: int = 16
@@ -160,6 +167,8 @@ class ServingConfig:
 
     ber: float = 0.0
     seed: int = 0
+
+    record_logits: bool = False
 
     # Online autopilot guard (README §Autopilot): an ``AutopilotConfig``
     # (runtime.config) arms the engine's per-window fault monitor — drifting

@@ -95,10 +95,23 @@ from .scheduler import Request, RequestState, Scheduler
 from .tiers import TierManager
 
 
-def engine_space(model: Any) -> ApproxSpace:
-    """The engine's default runtime: memory-forced, NaN/Inf-only, no
-    boundary scrub (the page repair manager owns every scrub), private to
-    this engine so stats streams stay isolated.
+# The pool's range guard: K/V lanes of magnitude >= 2**32 are fatal too.
+# A flip of one of the two top exponent bits multiplies a lane by 2**64 or
+# 2**128 — a legal float that NaN/Inf detection cannot see, and one that
+# overflows the f32 score and RMSNorm reductions downstream into a
+# non-finite readout.  Every such flip of a lane above 2**-32 lands at or
+# past the guard, while real K/V lanes stay orders of magnitude below it
+# (the same models serve in float16, whose largest value is 65504).
+KV_RANGE_GUARD = 2.0 ** 32
+
+
+def engine_space(model: Any, *, mesh: Any = None) -> ApproxSpace:
+    """The engine's default runtime: memory-forced, NaN/Inf plus the
+    ``KV_RANGE_GUARD`` range guard, no boundary scrub (the page repair
+    manager owns every scrub), private to this engine so stats streams stay
+    isolated.  ``mesh`` places it on a device mesh under the default
+    sharding rules (the pool's pages shard over "data" — the device-local
+    sharded walk).
 
     The default fill is ZERO (not the training default ``neighbor_mean``):
     KV lanes have no cheap neighborhood statistic on the decode hot path,
@@ -111,11 +124,22 @@ def engine_space(model: Any) -> ApproxSpace:
     fused vs fallback)."""
     return ApproxSpace(
         model.cfg.repair,
+        mesh=mesh,
         mode="memory",
         policy="zero",
-        max_magnitude=None,
+        max_magnitude=KV_RANGE_GUARD,
         scrub=ScrubSchedule(boundary=False, interval=0),
     )
+
+
+def _readout(nxt, rows, keep_rows: bool):
+    """A step's readout as ONE (2, B) int32 array — greedy tokens over a
+    per-row non-finite flag, so the engine counts NaN/Inf logits rows in
+    the same readback that fetches the tokens — plus the f32 logits rows
+    themselves when the engine records them (``None`` otherwise)."""
+    bad = ~jnp.all(jnp.isfinite(rows), axis=-1)
+    out = jnp.stack([nxt, bad.astype(jnp.int32)])
+    return out, (rows.astype(jnp.float32) if keep_rows else None)
 
 
 # ---------------------------------------------------------------------------
@@ -276,9 +300,15 @@ class Engine:
         )
         # the one greedy step builder (shared with launch.serve.generate, so
         # the engine-vs-generate token-parity contract cannot drift)
-        self._step_fn = jax.jit(
-            self.space.wrap_serve_step(build_serve_step(model))
-        )
+        serve_step = self.space.wrap_serve_step(build_serve_step(model))
+        keep = self.cfg.record_logits
+
+        def gathered_step(params, view, batch, pos, stats):
+            nxt, logits, view, stats = serve_step(params, view, batch, pos, stats)
+            out, rows = _readout(nxt, logits[:, -1, :], keep)
+            return out, rows, view, stats
+
+        self._step_fn = jax.jit(gathered_step)
         # fused paged decode: compiled once against the pool rules' static
         # repair spec; None keeps the gathered-view fallback
         self.paged_plan = (
@@ -300,6 +330,11 @@ class Engine:
         )
         self._prefilling: List[Request] = []   # mid-prefill (chunk) lane
         self.kernel_counts = np.zeros(8, np.int64)   # fused AT_* totals
+        # generated tokens whose readout logits held a NaN/Inf, on every
+        # path — what repair exists to keep at zero
+        self.nonfinite_rows = 0
+        # rid -> recorded readout logits rows (ServingConfig.record_logits)
+        self._logits: Dict[int, List[np.ndarray]] = {}
         # desynchronized stats drain (drain_interval > 0): fused-lane
         # counters accumulate on device; one concatenated readback per
         # drain window feeds the reactive scrub
@@ -655,6 +690,7 @@ class Engine:
         model, n_rows = self.model, self.cfg.n_pages + 1
         split_k = self._split_k
         shard = self._kernel_shard
+        keep = self.cfg.record_logits
 
         def paged_step(params, pool_tree, batch, bt, pos, stats):
             logits, pool_tree, slot_counts, counts = model.serve_step_paged(
@@ -663,10 +699,11 @@ class Engine:
                 shard=shard,
             )
             nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+            out, rows = _readout(nxt, logits[:, -1, :], keep)
             page_counts = jnp.zeros((n_rows,), jnp.int32).at[bt].add(
                 slot_counts
             )
-            return nxt, pool_tree, page_counts, counts, stats
+            return out, rows, pool_tree, page_counts, counts, stats
 
         return jax.jit(paged_step, donate_argnums=(1,))
 
@@ -678,6 +715,7 @@ class Engine:
         share the executable with full chunks)."""
         model, n_rows = self.model, self.cfg.n_pages + 1
         shard = self._kernel_shard
+        keep = self.cfg.record_logits
 
         def prefill_step(params, pool_tree, batch, bt, q_start, q_len, stats):
             logits, pool_tree, slot_counts, counts = model.prefill_paged(
@@ -686,14 +724,15 @@ class Engine:
                 shard=shard,
             )
             last = jnp.maximum(q_len - 1, 0)
-            nxt = jnp.argmax(
-                jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0],
-                axis=-1,
-            ).astype(jnp.int32)
+            readout = jnp.take_along_axis(
+                logits, last[:, None, None], axis=1
+            )[:, 0]
+            nxt = jnp.argmax(readout, axis=-1).astype(jnp.int32)
+            out, rows = _readout(nxt, readout, keep)
             page_counts = jnp.zeros((n_rows,), jnp.int32).at[bt].add(
                 slot_counts
             )
-            return nxt, pool_tree, page_counts, counts, stats
+            return out, rows, pool_tree, page_counts, counts, stats
 
         return jax.jit(prefill_step, donate_argnums=(1,))
 
@@ -714,7 +753,7 @@ class Engine:
         bt = self.pool.block_table(req.pages)[None, :]
         view = self.pool.gather(bt)
         tokens = jnp.asarray([toks[n_cached:]], jnp.int32)
-        nxt, _, view, self._stream = self._step_fn(
+        out, rows, view, self._stream = self._step_fn(
             self.params, view, {"tokens": tokens},
             jnp.asarray(n_cached, jnp.int32), self._stream,
         )
@@ -726,9 +765,7 @@ class Engine:
             # work the engine already did once — the recompute bill the
             # tier swap exists to avoid
             self.prefill_tokens_recomputed += len(toks) - n_cached
-        tok = int(self._host(nxt)[0])
-        req.tokens.append(tok)
-        emitted.setdefault(req.rid, []).append(tok)
+        self._emit([req], out, rows, emitted, slots=[0])
 
     def _prefill_paged(
         self, req: Request, emitted: Dict[int, List[int]]
@@ -751,7 +788,7 @@ class Engine:
         q_len = len(chunk)
         padded = chunk + [0] * (width - q_len)
         bt = self.pool.block_table(req.pages)[None, :]
-        nxt, self.pool.tree, page_counts, counts, self._stream = (
+        out, rows, self.pool.tree, page_counts, counts, self._stream = (
             self._prefill_fn(
                 self.params, self.pool.tree,
                 {"tokens": jnp.asarray([padded], jnp.int32)},
@@ -767,9 +804,7 @@ class Engine:
             self.prefill_tokens_saved += req.cached_tokens
             if req.n_preempted:
                 self.prefill_tokens_recomputed += len(toks) - req.cached_tokens
-            tok = int(self._host(nxt)[0])
-            req.tokens.append(tok)
-            emitted.setdefault(req.rid, []).append(tok)
+            self._emit([req], out, rows, emitted, slots=[0])
         return page_counts, counts, done
 
     def _decode_batch(
@@ -786,13 +821,22 @@ class Engine:
             pos[req.slot] = req.pos
         return bt, tokens, pos
 
-    def _emit(self, reqs, nxt, emitted) -> None:
-        nxt = self._host(nxt)
-        for req in reqs:
-            tok = int(nxt[req.slot])
+    def _emit(self, reqs, out, rows, emitted, slots=None) -> None:
+        """Append each request's greedy token from its readout row (its
+        decode slot, or ``slots``) — one readback of the step's
+        ``_readout``, a second for the logits rows when recorded — and
+        count non-finite readout rows."""
+        out = self._host(out)
+        rows = self._host(rows) if rows is not None else None
+        if slots is None:
+            slots = [req.slot for req in reqs]
+        for req, i in zip(reqs, slots):
+            tok = int(out[0, i])
+            self.nonfinite_rows += int(out[1, i])
             req.tokens.append(tok)
-            req.pos += 1
             emitted.setdefault(req.rid, []).append(tok)
+            if rows is not None:
+                self._logits.setdefault(req.rid, []).append(rows[i])
 
     def _decode(
         self, reqs: List[Request], emitted: Dict[int, List[int]]
@@ -800,12 +844,14 @@ class Engine:
         """Gathered-view decode (the PR-2 fallback path)."""
         bt, tokens, pos = self._decode_batch(reqs)
         view = self.pool.gather(bt)
-        nxt, _, view, self._stream = self._step_fn(
+        out, rows, view, self._stream = self._step_fn(
             self.params, view, {"tokens": jnp.asarray(tokens)},
             jnp.asarray(pos), self._stream,
         )
         self.pool.scatter(view, bt)
-        self._emit(reqs, nxt, emitted)
+        self._emit(reqs, out, rows, emitted)
+        for req in reqs:
+            req.pos += 1
 
     def _decode_paged(
         self, reqs: List[Request], emitted: Dict[int, List[int]]
@@ -816,13 +862,15 @@ class Engine:
         (the reactive detector's input — read back by the lane flush or a
         later drain, never here)."""
         bt, tokens, pos = self._decode_batch(reqs)
-        nxt, self.pool.tree, page_counts, counts, self._stream = (
+        out, rows, self.pool.tree, page_counts, counts, self._stream = (
             self._paged_fn(
                 self.params, self.pool.tree, {"tokens": jnp.asarray(tokens)},
                 jnp.asarray(bt), jnp.asarray(pos), self._stream,
             )
         )
-        self._emit(reqs, nxt, emitted)
+        self._emit(reqs, out, rows, emitted)
+        for req in reqs:
+            req.pos += 1
         return page_counts, counts
 
     def _maybe_finish(self, req: Request) -> bool:
@@ -835,6 +883,10 @@ class Engine:
                 "n_preempted": req.n_preempted,
                 "truncated": req.truncated,
             }
+            if self.cfg.record_logits:
+                self.results[req.rid]["logits"] = np.stack(
+                    self._logits.pop(req.rid)
+                )
             return True
         return False
 
@@ -908,6 +960,7 @@ class Engine:
             "pool_gathers": self.pool.n_gathers,
             "pool_scatters": self.pool.n_scatters,
             "paged_kernel_events": int(self.kernel_counts[6]),  # AT_EV_TOTAL
+            "nonfinite_logit_rows": self.nonfinite_rows,
             "autopilot_trips": self.autopilot_trips,
             **self.repair.summary(),
         }

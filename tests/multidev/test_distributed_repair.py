@@ -21,6 +21,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import stats as stats_lib
+from repro.launch.mesh import make_mesh
 from repro.runtime import ApproxConfig, ApproxSpace
 from repro.runtime.space import inject_tree, scrub_tree
 
@@ -35,7 +36,7 @@ pytestmark = [
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return make_mesh((4, 2), ("data", "model"))
 
 
 def poisoned_tree(seed=0):
@@ -297,7 +298,7 @@ def test_elastic_reshard_restore_and_reference_repair(mesh, tmp_path):
     from repro.checkpoint.manager import CheckpointManager
 
     mesh_a = mesh                                     # (data=4, model=2)
-    mesh_b = jax.make_mesh((2, 4), ("data", "model"))  # restored topology
+    mesh_b = make_mesh((2, 4), ("data", "model"))  # restored topology
 
     tree = poisoned_tree(3)
     tree = {  # clean state for the save (scrub-on-save would fix it anyway)

@@ -9,7 +9,7 @@ injected/poisoned flips:
   * integer ledgers (slot_counts, counts) — bit-identical to the SERIAL
     kernel: every block slot is owned by exactly one device;
   * float output — bit-identical to `paged_*_shard_ref`, the single-device
-    oracle running the identical ownership partition + device-major LSE
+    oracle running the identical ownership partition + per-split device
     merge (the serial kernel groups its accumulation differently, so its
     float output is only allclose);
   * engine end-to-end — same tokens as the single-device engine, zero
@@ -26,6 +26,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels import paged_attention as pk
+from repro.launch.mesh import make_mesh
 from repro.runtime import ApproxConfig, ApproxSpace
 
 pytestmark = [
@@ -41,7 +42,7 @@ N_SHARDS = 4          # the mesh's "data" axis
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((N_SHARDS, 2), ("data", "model"))
+    return make_mesh((N_SHARDS, 2), ("data", "model"))
 
 
 def _pool(seed=0, P_pages=8, L=1, pg=4, Kh=2, Dh=8):
@@ -244,3 +245,32 @@ def test_traffic_sharded_token_parity(mesh):
     rep_p = drive(plain, arrivals)
     assert rep_s["token_streams"] == rep_p["token_streams"]
     assert rep_s["tokens_emitted"] == rep_p["tokens_emitted"] > 0
+
+
+def test_chip_smoke_four_chip_phase(monkeypatch):
+    """``chip_smoke.py --chips 4``'s comparison at a tiny f32 size on a
+    (4, 1) mesh: the engine's default space with a mesh engages the sharded
+    walk, and on the contexts both engines shared its logits match the
+    one-device engine's and the gathered jnp path's to f32 rounding."""
+    import importlib
+    import pathlib
+    import sys
+
+    from conftest import tiny_transformer
+
+    monkeypatch.setenv("REPRO_KERNEL_PLANS", "1")    # kernel page scrub on CPU
+    monkeypatch.syspath_prepend(str(pathlib.Path(__file__).resolve().parents[2]))
+    cs = importlib.import_module("chip_smoke")
+    model, params = tiny_transformer()
+    mesh4 = make_mesh((4, 1), ("data", "model"), devices=jax.devices()[:4])
+    geometry = dict(page_size=4, n_pages=31, max_batch=2,
+                    max_pages_per_request=8, prefill_chunk=8, repair="page")
+    prompts = cs.make_prompts(0, model.cfg.vocab, 3, 5, 20)
+    out = cs.compare_sharded(model, params, prompts, mesh=mesh4, seed=0,
+                             geometry=geometry, max_new=4, interpret=True)
+    sys.modules.pop("chip_smoke", None)
+    assert out["sharded_kernels"]
+    assert out["identical"] == len(prompts)
+    assert out["shared_positions"] == [4] * len(prompts)
+    assert out["d_shard"] <= 1e-5 and out["d_ref"] <= 1e-4
+    assert out["d_shard"] <= cs.SHARD_TOL * out["d_ref"]

@@ -25,7 +25,7 @@ DTYPES = [jnp.float16, jnp.float32, jnp.bfloat16, jnp.float64]
 def _scope(dtype):
     """float64 bit views need x64 enabled; everything else runs as-is."""
     if jnp.dtype(dtype) == jnp.float64:
-        return jax.experimental.enable_x64()
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 

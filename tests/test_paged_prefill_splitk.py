@@ -201,6 +201,44 @@ def test_splitk_matches_serial_over_wide_walk():
         )
 
 
+def test_shard_merge_passes_single_owner_splits_through():
+    """The sharded walk's merge combines each split's device partials
+    before the splits: a split whose pages one device owns reaches the
+    split merge bit for bit as the single-device split-K partial, so the
+    merged output equals the one-device merge exactly."""
+    key = jax.random.PRNGKey(11)
+    k_pages, v_pages = _pool(key, P=8)
+    k_pages = k_pages.at[4, 1, 2, 0, 3].set(jnp.nan)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (2, 4, 16), jnp.float32)
+    # 8 rows over 4 shards of 2: every 2-slot split lies on one shard
+    bt = jnp.asarray([[0, 1, 4, 5], [2, 3, 7, 7]], jnp.int32)
+    pos = jnp.asarray([13, 6], jnp.int32)
+    pos_slot = jnp.broadcast_to(pos[:, None], bt.shape)
+    kw = dict(
+        splits=2, consts=pa._detector_consts("default", "default",
+                                             k_pages.dtype, True),
+        policy_k="zero", constant_k=0.0, policy_v="zero", constant_v=0.0,
+        interpret=True,
+    )
+    layer = jnp.int32(1)
+    o1, m1, l1, _, _ = pa._splitk_partials(
+        q, k_pages, v_pages, bt, pos_slot, layer, **kw
+    )
+    parts = []
+    for lo in range(0, 8, 2):
+        owned, bt_local = pa._owned_remap(bt, lo, 2)
+        parts.append(pa._splitk_partials(
+            q, k_pages[lo:lo + 2], v_pages[lo:lo + 2], bt_local,
+            jnp.where(owned, pos_slot, -1), layer, **kw,
+        )[:3])
+    o, m, l = (jnp.stack(x, axis=1) for x in zip(*parts))
+    sharded = pa._shard_merge(jnp.float32, o, m, l)
+    one = pa._lse_merge(jnp.float32, o1, m1, l1)
+    np.testing.assert_array_equal(
+        np.asarray(sharded).view(np.uint32), np.asarray(one).view(np.uint32)
+    )
+
+
 def test_splitk_ragged_null_tail_regression():
     """A request whose valid pages occupy only the FIRST split leaves the
     remaining splits entirely null — those must contribute -inf logits to

@@ -195,3 +195,35 @@ def test_serving_config_validation():
     cfg = ServingConfig(page_size=4, max_pages_per_request=3)
     assert cfg.max_seq == 12
     assert cfg.pages_for(9) == 3
+
+
+@pytest.mark.parametrize("paged_decode", ["auto", "off"])
+def test_range_guard_keeps_readouts_finite(model_params, paged_decode):
+    """Exponent flips that leave huge finite K/V lanes pass NaN/Inf
+    detection and overflow into non-finite logits; the engine counts those
+    readout rows on the fused and the gathered path alike, and its default
+    space's range guard keeps them at zero."""
+    from repro.serving import engine_space
+
+    model, params = model_params
+    spaces = {
+        "nan_inf": lambda: ApproxSpace(
+            engine_space(model).config, max_magnitude=None
+        ),
+        "default": lambda: engine_space(model),
+    }
+    rows = {}
+    for name, make_space in spaces.items():
+        eng = Engine(model, params, ServingConfig(
+            page_size=4, n_pages=16, max_batch=4, max_pages_per_request=4,
+            paged_decode=paged_decode, ber=3e-3, seed=1,
+        ), space=make_space())
+        assert (eng._paged_fn is not None) == (paged_decode == "auto")
+        for i in range(4):
+            prompt = jax.random.randint(jax.random.PRNGKey(i), (6,), 1, 96)
+            eng.add_request(prompt, max_new=8)
+        eng.run()
+        assert eng.stats_dict()["flips"] > 0
+        rows[name] = eng.metrics()["nonfinite_logit_rows"]
+    assert rows["nan_inf"] > 0
+    assert rows["default"] == 0
