@@ -1,0 +1,11 @@
+import os
+import sys
+import pathlib
+
+# the tests drive the harness on the CPU (Pallas in interpret mode); none
+# of them needs or loads the accelerator's library
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
