@@ -106,7 +106,6 @@ def drive(
         "n_preemptions": m["n_preemptions"],
         "n_host_syncs": m["n_host_syncs"],
         "host_syncs_per_step": m["host_syncs_per_step"],
-        "stage_wall_s": m["stage_wall_s"],
     }
 
 

@@ -145,6 +145,15 @@ def _kernel_eligible(leaves, regions, rule_tree, trigger, scope="tree") -> bool:
     return True
 
 
+# program name of each executable kind (``jit_<name>`` in a profile)
+_PROGRAM_NAMES = {
+    "tree": "repair_tree",
+    "pages": "repair_pages",
+    "reference": "repair_reference",
+    "inject": "inject",
+}
+
+
 def _bucket(n: int, cap: int) -> int:
     """Next power of two ≥ n, clamped to the page-axis size."""
     b = 1
@@ -394,6 +403,9 @@ class RepairPlan:
         else:  # pragma: no cover
             raise ValueError(f"bad executable kind {kind!r}")
 
+        # a stable program name per kind: ``jit_repair_pages`` etc. in a
+        # profile, where every closure would otherwise be ``jit_fn``
+        fn.__name__ = fn.__qualname__ = _PROGRAM_NAMES[kind]
         return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 

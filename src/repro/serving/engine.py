@@ -66,7 +66,8 @@ engine — "Sharded decode & load testing"):
   repair on read with a value-independent fill, so deferring the HBM
   scrub never changes the tokens.  ``Engine.metrics()`` reports
   ``n_host_syncs`` — the blocking device→host readback count the drain
-  exists to shrink — plus per-stage wall-clock totals.
+  exists to shrink.  Each readback is also a profiler span
+  (``engine.readback``), as is every stage of a step (``Engine.step``).
 
 ``launch.serve.generate(..., paged=True)`` is the single-request degenerate
 case of this engine.
@@ -74,12 +75,12 @@ case of this engine.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..core import stats as stats_lib
 from ..core.regions import Region
@@ -269,10 +270,6 @@ class Engine:
         # observation counters the hot path reports through (must exist
         # before any helper that syncs is first called)
         self.n_host_syncs = 0
-        self.stage_wall_s: Dict[str, float] = {
-            "admit": 0.0, "prefill": 0.0, "decode": 0.0,
-            "repair": 0.0, "guard": 0.0,
-        }
         # device-local sharded hot path: engaged only when the pool's page
         # axis is genuinely sharded over exactly one mesh axis (divisible
         # row count) — otherwise the single-device kernel walk stays
@@ -296,7 +293,7 @@ class Engine:
         )
         self.repair = PageRepairManager(
             self.pool, self.space, self.cfg,
-            on_host_sync=self._note_host_sync,
+            readback=self._host,
         )
         # the one greedy step builder (shared with launch.serve.generate, so
         # the engine-vs-generate token-parity contract cannot drift)
@@ -387,7 +384,19 @@ class Engine:
 
     # ------------------------------------------------------------------- step
     def step(self) -> Dict[str, Any]:
-        """One engine step; returns the tokens emitted and requests finished."""
+        """One engine step; returns the tokens emitted and requests finished.
+
+        The step is the profiler span ``engine.step`` (``step_num`` = the
+        step index) and each stage below a child span — ``engine.drain``,
+        ``engine.admit``, ``engine.prefill``, ``engine.decode``,
+        ``engine.sweep``, ``engine.guard`` — with ``engine.prefill_chunk``,
+        ``engine.repair``, ``engine.readback`` and ``pool.reset_pages``
+        beneath them, all on the profiler's clock (README §Serving engine —
+        "Profiler spans")."""
+        with StepTraceAnnotation("engine.step", step_num=self._t):
+            return self._step()
+
+    def _step(self) -> Dict[str, Any]:
         t = self._t
         self.pool.now = t        # dwell clock: one step = one fault window
         emitted: Dict[int, List[int]] = {}
@@ -401,7 +410,8 @@ class Engine:
         # engine scrubbed inside the previous step — the pool bits entering
         # stage (1) are identical and the token trajectory replays
         if self._desync and self._steps_since_drain >= self.cfg.drain_interval:
-            self._drain_pending()
+            with TraceAnnotation("engine.drain"):
+                self._drain_pending()
 
         # (1) simulation boundary: one window of flips strikes the pool —
         # the same stats-threading injection entry point the train loop's
@@ -418,8 +428,52 @@ class Engine:
         # recompute victim restarts from scratch when re-admitted, a swap
         # victim rejoins the lane at its saved chunk position on swap-in.
         # (On the gathered fallback the whole-prompt prefill rides inside
-        # admission, so its wall time lands in the "admit" bucket.)
-        t_admit = time.perf_counter()
+        # admission, so its spans nest in ``engine.admit``.)
+        with TraceAnnotation("engine.admit") as span:
+            plan = self._admit(emitted, finished)
+            span.set_metadata(admitted=len(plan.admitted))
+
+        # (3) the fused prefill lane: one prompt chunk per mid-prefill
+        # request, straight off the pool, then ONE reactive pass from the
+        # summed per-page fatal counts (per-request passes would scrub a
+        # faulty shared/null page once per request — the gathered path
+        # charges it once per step).  The counter vectors stay on device
+        # through the lane; `_flush_lane` reads them back (lockstep) or
+        # parks them in the pending accumulator (desync).
+        if self._prefilling:
+            with TraceAnnotation(
+                "engine.prefill", requests=len(self._prefilling)
+            ):
+                self._prefill_lane(emitted, finished)
+
+        # (4) one decode step + the reactive repair pass.
+        if plan.decode:
+            with TraceAnnotation("engine.decode") as span:
+                n = self._decode_stage(plan.decode, emitted, finished)
+                span.set_metadata(batch=n)
+
+        # (5) background sweep tick
+        with TraceAnnotation("engine.sweep"):
+            self._stream = self.repair.sweep_step(t, self._stream)
+
+        # (6) autopilot guard: close the observation window
+        if self.guard is not None:
+            with TraceAnnotation("engine.guard"):
+                self._guard_tick()
+
+        if self._desync:
+            self._steps_since_drain += 1
+        self._t += 1
+        for rid, toks in emitted.items():
+            self.tokens_emitted += len(toks)
+        return {"t": t, "emitted": emitted, "finished": finished}
+
+    def _admit(
+        self, emitted: Dict[int, List[int]], finished: List[int]
+    ) -> Any:
+        """Stage (2): the scheduler's plan for this step, page allocation,
+        prefix-cache hits, swap-ins and (gathered fallback) whole-prompt
+        prefills.  Returns the plan."""
         self._prefilling = [
             r for r in self._prefilling if r.state is RequestState.RUNNING
         ]
@@ -478,121 +532,105 @@ class Engine:
                 self.cache.insert(req)
             if req.state is RequestState.RUNNING and self._maybe_finish(req):
                 finished.append(req.rid)
-        self.stage_wall_s["admit"] += time.perf_counter() - t_admit
+        return plan
 
-        # (3) the fused prefill lane: one prompt chunk per mid-prefill
-        # request, straight off the pool, then ONE reactive pass from the
-        # summed per-page fatal counts (per-request passes would scrub a
-        # faulty shared/null page once per request — the gathered path
-        # charges it once per step).  The counter vectors stay on device
-        # through the lane; `_flush_lane` reads them back (lockstep) or
-        # parks them in the pending accumulator (desync).
-        if self._prefilling:
-            t_pre = time.perf_counter()
-            page_counts = counts = None
-            covered = {self.pool.null_page}
-            still: List[Request] = []
-            for req in self._prefilling:
-                pc_r, cnt_r, done = self._prefill_paged(req, emitted)
-                page_counts = pc_r if page_counts is None else page_counts + pc_r
-                counts = cnt_r if counts is None else counts + cnt_r
-                covered.update(req.pages)
-                if not done:
-                    still.append(req)
-                    continue
-                if self.cache is not None:
-                    self.cache.insert(req)
-                if req.state is RequestState.RUNNING and self._maybe_finish(req):
-                    finished.append(req.rid)
-            self._prefilling = still
-            self._last_touched = sorted(
-                set(self._last_touched) | (covered - {self.pool.null_page})
-            )
-            self.stage_wall_s["prefill"] += time.perf_counter() - t_pre
-            self._flush_lane(page_counts, counts, covered)
+    def _prefill_lane(
+        self, emitted: Dict[int, List[int]], finished: List[int]
+    ) -> None:
+        """Stage (3): one chunk per mid-prefill request, then the lane's
+        reactive pass."""
+        page_counts = counts = None
+        covered = {self.pool.null_page}
+        still: List[Request] = []
+        for req in self._prefilling:
+            pc_r, cnt_r, done = self._prefill_paged(req, emitted)
+            page_counts = pc_r if page_counts is None else page_counts + pc_r
+            counts = cnt_r if counts is None else counts + cnt_r
+            covered.update(req.pages)
+            if not done:
+                still.append(req)
+                continue
+            if self.cache is not None:
+                self.cache.insert(req)
+            if req.state is RequestState.RUNNING and self._maybe_finish(req):
+                finished.append(req.rid)
+        self._prefilling = still
+        self._last_touched = sorted(
+            set(self._last_touched) | (covered - {self.pool.null_page})
+        )
+        self._flush_lane(page_counts, counts, covered)
 
-        # (4) one decode step + the reactive repair pass.  Reserving a page
-        # for one request may preempt another — both one that hasn't
-        # reserved yet (inner state check) and one that already did (final
-        # filter): victims never reach the decode batch.
+    def _decode_stage(
+        self,
+        planned: List[Request],
+        emitted: Dict[int, List[int]],
+        finished: List[int],
+    ) -> int:
+        """Stage (4): reserve each planned request's next page, one decode
+        step over those still running, the reactive repair pass.  Reserving
+        a page for one request may preempt another — both one that hasn't
+        reserved yet (inner state check) and one that already did (final
+        filter): victims never reach the decode batch.  Returns the batch
+        size."""
         decodable = []
-        for r in plan.decode:
+        for r in planned:
             if r.state is not RequestState.RUNNING:
                 continue
             if self._reserve_next_page(r):
                 decodable.append(r)
         decodable = [r for r in decodable if r.state is RequestState.RUNNING]
-        if decodable:
-            touched = sorted(
-                set(self._last_touched)
-                | {p for r in decodable for p in r.pages}
+        if not decodable:
+            return 0
+        touched = sorted(
+            set(self._last_touched)
+            | {p for r in decodable for p in r.pages}
+        )
+        self._last_touched = touched
+        if self._paged_fn is not None:
+            # fused path: the kernel repairs fatal lanes on read and IS
+            # the detector — decode first, then scrub the resident pool
+            # pages its per-page counts flagged (reactive write-back)
+            page_counts, counts = self._decode_paged(decodable, emitted)
+            self._flush_lane(
+                page_counts, counts, set(touched) | {self.pool.null_page}
             )
-            self._last_touched = touched
-            if self._paged_fn is not None:
-                # fused path: the kernel repairs fatal lanes on read and IS
-                # the detector — decode first, then scrub the resident pool
-                # pages its per-page counts flagged (reactive write-back)
-                t_dec = time.perf_counter()
-                page_counts, counts = self._decode_paged(decodable, emitted)
-                self.stage_wall_s["decode"] += time.perf_counter() - t_dec
-                self._flush_lane(
-                    page_counts, counts, set(touched) | {self.pool.null_page}
-                )
-            else:
-                t_rep = time.perf_counter()
-                self._stream = self.repair.repair_step(touched, self._stream)
-                self.stage_wall_s["repair"] += time.perf_counter() - t_rep
-                t_dec = time.perf_counter()
-                self._decode(decodable, emitted)
-                self.stage_wall_s["decode"] += time.perf_counter() - t_dec
-            for req in decodable:
-                if self._maybe_finish(req):
-                    finished.append(req.rid)
+        else:
+            self._stream = self.repair.repair_step(touched, self._stream)
+            self._decode(decodable, emitted)
+        for req in decodable:
+            if self._maybe_finish(req):
+                finished.append(req.rid)
+        return len(decodable)
 
-        # (5) background sweep tick
-        t_rep = time.perf_counter()
-        self._stream = self.repair.sweep_step(t, self._stream)
-        self.stage_wall_s["repair"] += time.perf_counter() - t_rep
-
-        # (6) autopilot guard: close the observation window; a trip swapped
-        # the pool RuleSet, so the fused executables that closed over the
-        # old rules' detectors/fills must be rebuilt (the gathered _step_fn
-        # is rules-independent — the engine space never scrubs in-step)
-        if self.guard is not None:
-            t_grd = time.perf_counter()
-            decisions = self.guard.tick()
-            if decisions:
-                self.autopilot_trips += len(decisions)
-                self.paged_plan = (
-                    _paged_decode_plan(
-                        self.model, self.space, self.pool, self.cfg
-                    )
-                    if self.cfg.paged_decode == "auto" else None
-                )
-                self._paged_fn = (
-                    self._build_paged_step(self.paged_plan)
-                    if self.paged_plan is not None else None
-                )
-                self._prefill_fn = (
-                    self._build_paged_prefill_step(self.paged_plan)
-                    if self.paged_plan is not None and self.paged_plan.prefill
-                    else None
-                )
-                # a trip may have forced the gathered fallback — flush any
-                # deferred counters before the fused path goes away
-                self._desync = (
-                    self.cfg.drain_interval > 0 and self._paged_fn is not None
-                )
-                if not self._desync:
-                    self.drain()
-            self.stage_wall_s["guard"] += time.perf_counter() - t_grd
-
-        if self._desync:
-            self._steps_since_drain += 1
-        self._t += 1
-        for rid, toks in emitted.items():
-            self.tokens_emitted += len(toks)
-        return {"t": t, "emitted": emitted, "finished": finished}
+    def _guard_tick(self) -> None:
+        """Stage (6): close the guard's observation window.  A trip swapped
+        the pool RuleSet, so the fused executables that closed over the old
+        rules' detectors/fills must be rebuilt (the gathered _step_fn is
+        rules-independent — the engine space never scrubs in-step)."""
+        decisions = self.guard.tick()
+        if not decisions:
+            return
+        self.autopilot_trips += len(decisions)
+        self.paged_plan = (
+            _paged_decode_plan(self.model, self.space, self.pool, self.cfg)
+            if self.cfg.paged_decode == "auto" else None
+        )
+        self._paged_fn = (
+            self._build_paged_step(self.paged_plan)
+            if self.paged_plan is not None else None
+        )
+        self._prefill_fn = (
+            self._build_paged_prefill_step(self.paged_plan)
+            if self.paged_plan is not None and self.paged_plan.prefill
+            else None
+        )
+        # a trip may have forced the gathered fallback — flush any
+        # deferred counters before the fused path goes away
+        self._desync = (
+            self.cfg.drain_interval > 0 and self._paged_fn is not None
+        )
+        if not self._desync:
+            self.drain()
 
     def run(self, max_idle_steps: int = 100) -> Dict[int, Dict[str, Any]]:
         """Drive the engine until every queued request finishes.  Long
@@ -611,14 +649,14 @@ class Engine:
         return self.results
 
     # ----------------------------------------------------- stats drain
-    def _note_host_sync(self) -> None:
-        self.n_host_syncs += 1
-
     def _host(self, x) -> np.ndarray:
         """Blocking device→host readback — every hot-path sync funnels
-        through here so ``metrics()["n_host_syncs"]`` audits them all."""
-        self.n_host_syncs += 1
-        return np.asarray(x)
+        through here (the repair manager's too) so
+        ``metrics()["n_host_syncs"]`` audits them all, and each is one
+        ``engine.readback`` profiler span."""
+        with TraceAnnotation("engine.readback"):
+            self.n_host_syncs += 1
+            return np.asarray(x)
 
     def _flush_lane(self, page_counts, counts, covered) -> None:
         """One fused lane's kernel counters.  Lockstep: read both vectors
@@ -640,9 +678,7 @@ class Engine:
             return
         pc = self._host(page_counts)
         self.kernel_counts += self._host(counts).astype(np.int64)
-        t0 = time.perf_counter()
         self._stream = self.repair.repair_counts(pc, covered, self._stream)
-        self.stage_wall_s["repair"] += time.perf_counter() - t0
 
     def _resolve_attr(self) -> None:
         """Charge the per-page ledger with the event deltas a drain-time
@@ -668,11 +704,9 @@ class Engine:
         covered = self._pending_covered
         self._pending = None
         self._pending_covered = set()
-        t0 = time.perf_counter()
         self._stream = self.repair.repair_counts(
             page_counts, covered, self._stream, defer=self._pending_attr
         )
-        self.stage_wall_s["repair"] += time.perf_counter() - t0
 
     def drain(self) -> None:
         """Flush every deferred readback: the pending kernel counters, the
@@ -750,22 +784,24 @@ class Engine:
         at cache position ``req.cached_tokens``."""
         toks = req.prefill_tokens()
         n_cached = req.cached_tokens
-        bt = self.pool.block_table(req.pages)[None, :]
-        view = self.pool.gather(bt)
-        tokens = jnp.asarray([toks[n_cached:]], jnp.int32)
-        out, rows, view, self._stream = self._step_fn(
-            self.params, view, {"tokens": tokens},
-            jnp.asarray(n_cached, jnp.int32), self._stream,
-        )
-        self.pool.scatter(view, bt)
-        req.pos = len(toks)
-        self.prefill_tokens_saved += n_cached
-        if req.n_preempted:
-            # every non-cached token of a post-preemption re-prefill is
-            # work the engine already did once — the recompute bill the
-            # tier swap exists to avoid
-            self.prefill_tokens_recomputed += len(toks) - n_cached
-        self._emit([req], out, rows, emitted, slots=[0])
+        with TraceAnnotation("engine.prefill_chunk", rid=req.rid,
+                             q_start=n_cached, q_len=len(toks) - n_cached):
+            bt = self.pool.block_table(req.pages)[None, :]
+            view = self.pool.gather(bt)
+            tokens = jnp.asarray([toks[n_cached:]], jnp.int32)
+            out, rows, view, self._stream = self._step_fn(
+                self.params, view, {"tokens": tokens},
+                jnp.asarray(n_cached, jnp.int32), self._stream,
+            )
+            self.pool.scatter(view, bt)
+            req.pos = len(toks)
+            self.prefill_tokens_saved += n_cached
+            if req.n_preempted:
+                # every non-cached token of a post-preemption re-prefill is
+                # work the engine already did once — the recompute bill the
+                # tier swap exists to avoid
+                self.prefill_tokens_recomputed += len(toks) - n_cached
+            self._emit([req], out, rows, emitted, slots=[0])
 
     def _prefill_paged(
         self, req: Request, emitted: Dict[int, List[int]]
@@ -787,24 +823,28 @@ class Engine:
         chunk = rest[:width]
         q_len = len(chunk)
         padded = chunk + [0] * (width - q_len)
-        bt = self.pool.block_table(req.pages)[None, :]
-        out, rows, self.pool.tree, page_counts, counts, self._stream = (
-            self._prefill_fn(
-                self.params, self.pool.tree,
-                {"tokens": jnp.asarray([padded], jnp.int32)},
-                jnp.asarray(bt), jnp.asarray([start], jnp.int32),
-                jnp.asarray([q_len], jnp.int32), self._stream,
+        with TraceAnnotation("engine.prefill_chunk", rid=req.rid,
+                             q_start=start, q_len=q_len):
+            bt = self.pool.block_table(req.pages)[None, :]
+            out, rows, self.pool.tree, page_counts, counts, self._stream = (
+                self._prefill_fn(
+                    self.params, self.pool.tree,
+                    {"tokens": jnp.asarray([padded], jnp.int32)},
+                    jnp.asarray(bt), jnp.asarray([start], jnp.int32),
+                    jnp.asarray([q_len], jnp.int32), self._stream,
+                )
             )
-        )
-        req.prefill_pos += q_len
-        done = start + q_len >= len(toks)
-        if done:
-            req.pos = len(toks)
-            req.prefill_pos = None
-            self.prefill_tokens_saved += req.cached_tokens
-            if req.n_preempted:
-                self.prefill_tokens_recomputed += len(toks) - req.cached_tokens
-            self._emit([req], out, rows, emitted, slots=[0])
+            req.prefill_pos += q_len
+            done = start + q_len >= len(toks)
+            if done:
+                req.pos = len(toks)
+                req.prefill_pos = None
+                self.prefill_tokens_saved += req.cached_tokens
+                if req.n_preempted:
+                    self.prefill_tokens_recomputed += (
+                        len(toks) - req.cached_tokens
+                    )
+                self._emit([req], out, rows, emitted, slots=[0])
         return page_counts, counts, done
 
     def _decode_batch(
@@ -946,7 +986,6 @@ class Engine:
             "host_syncs_per_step": self.n_host_syncs / steps,
             "drain_interval": self.cfg.drain_interval,
             "sharded_kernels": self._kernel_shard is not None,
-            "stage_wall_s": dict(self.stage_wall_s),
             "prefill_tokens_saved": self.prefill_tokens_saved,
             "prefill_tokens_recomputed": self.prefill_tokens_recomputed,
             "n_preemptions": self.sched.n_preemptions,

@@ -34,6 +34,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 
 from ..core import stats as stats_lib
@@ -49,9 +50,10 @@ def _is_float(leaf) -> bool:
 
 
 @jax.jit
-def _reset_pages(tree: Any, ids: jax.Array) -> Any:
+def pool_reset_pages(tree: Any, ids: jax.Array) -> Any:
     """Zero the named pages in one fused update (functional on CPU; on TPU
-    buffer donation would make this an in-place page clear)."""
+    buffer donation would make this an in-place page clear).  Its program
+    is ``jit_pool_reset_pages`` in a profile."""
     return jax.tree.map(
         lambda leaf: leaf.at[ids].set(0) if _is_float(leaf) else leaf, tree
     )
@@ -264,7 +266,10 @@ class PagedKVPool:
         if pages:
             # physical pages are recycled memory: reset so a new request
             # never reads a previous tenant's (possibly flipped) lanes
-            self.tree = _reset_pages(self.tree, jnp.asarray(pages, jnp.int32))
+            with TraceAnnotation("pool.reset_pages", pages=n):
+                self.tree = pool_reset_pages(
+                    self.tree, jnp.asarray(pages, jnp.int32)
+                )
             assert all(self._refcount[p] == 0 for p in pages), pages
             self._refcount[pages] = 1
             self.page_clean_step[pages] = self.now    # zeroed == scrubbed
@@ -362,7 +367,9 @@ class PagedKVPool:
         )
         return self._probe_fatal_pages(page_ids)
 
-    def _probe_fatal_pages(self, page_ids: Sequence[int]) -> List[int]:
+    def _probe_fatal_pages(
+        self, page_ids: Sequence[int], read=np.asarray
+    ) -> List[int]:
         """The subset of ``page_ids`` holding >=1 fatal lane — the trap
         analogue at page granularity (detection only; no repair).
 
@@ -374,7 +381,8 @@ class PagedKVPool:
         exact-region/exact-island leaves are never probed, and leaves a
         reactive pass would not repair must not keep re-flagging their
         pages as faulty — that would dispatch a no-op scrub every step
-        forever."""
+        forever.  ``read`` is the blocking readback of the page flags (the
+        engine passes its audited one)."""
         ids = sorted(set(page_ids))
         if not ids:
             return []
@@ -397,7 +405,7 @@ class PagedKVPool:
             flags = bad if flags is None else flags | bad
         if flags is None:
             return []
-        mask = np.asarray(flags)
+        mask = read(flags)
         return [p for p, b in zip(ids, mask) if b]
 
     def scrub_pages(

@@ -33,6 +33,7 @@ from __future__ import annotations
 from typing import Callable, Iterable, List, Optional, Sequence, Set
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import stats as stats_lib
 from ..kernels import ops as kernel_ops
@@ -49,7 +50,7 @@ class PageRepairManager:
         pool: PagedKVPool,
         space: ApproxSpace,
         cfg: ServingConfig,
-        on_host_sync: Optional[Callable[[], None]] = None,
+        readback: Optional[Callable[[object], np.ndarray]] = None,
     ):
         self.pool = pool
         self.space = space
@@ -59,10 +60,10 @@ class PageRepairManager:
         self._sweep_cursor = 0
         self.n_reactive_scrubs = 0
         self.n_sweep_scrubs = 0
-        # the engine's device->host readback counter: every point where this
-        # manager forces a blocking device read reports through it, so the
-        # desynchronized drain's "strictly fewer syncs" claim is auditable
-        self._on_host_sync = on_host_sync or (lambda: None)
+        # the engine's audited device->host readback: every blocking device
+        # read this manager forces goes through it, so the desynchronized
+        # drain's "strictly fewer syncs" claim is auditable
+        self._read = readback or np.asarray
 
     # ----------------------------------------------------------- kernel route
     def note_kernel(self, counts, touched: Iterable[int]) -> None:
@@ -96,9 +97,9 @@ class PageRepairManager:
         if scope == "none":
             return stats
         candidates = set(touched) | self._dirty | {self.pool.null_page}
-        self._on_host_sync()          # the probe blocks on a device read
-        faulty = self.pool._probe_fatal_pages(candidates)
-        return self._scrub_faulty(scope, faulty, stats)
+        with TraceAnnotation("engine.repair", pages=len(candidates)):
+            faulty = self.pool._probe_fatal_pages(candidates, read=self._read)
+            return self._scrub_faulty(scope, faulty, stats)
 
     def repair_counts(
         self,
@@ -134,15 +135,15 @@ class PageRepairManager:
         scope = serving_scope(self.cfg.repair)
         if scope == "none":
             return stats
-        counts = np.asarray(page_counts)
-        faulty = [int(p) for p in np.nonzero(counts > 0)[0]]
-        stale = self._dirty - set(covered)
-        if stale:
-            self._on_host_sync()
-            faulty = sorted(
-                set(faulty) | set(self.pool._probe_fatal_pages(stale))
-            )
-        return self._scrub_faulty(scope, faulty, stats, defer=defer)
+        with TraceAnnotation("engine.repair", pages=len(covered)):
+            counts = np.asarray(page_counts)
+            faulty = [int(p) for p in np.nonzero(counts > 0)[0]]
+            stale = self._dirty - set(covered)
+            if stale:
+                faulty = sorted(set(faulty) | set(
+                    self.pool._probe_fatal_pages(stale, read=self._read)
+                ))
+            return self._scrub_faulty(scope, faulty, stats, defer=defer)
 
     def _scrub_faulty(
         self,
@@ -159,8 +160,7 @@ class PageRepairManager:
             return stats
         events0 = stats["events"]
         if defer is None:
-            self._on_host_sync()
-            events0 = int(events0)
+            events0 = int(self._read(events0))
         stats = self.pool.scrub_scope(
             scope, scrub_set, stats, trigger="reactive"
         )
@@ -170,8 +170,7 @@ class PageRepairManager:
         if defer is not None:
             defer.append((list(faulty), stats["events"] - events0))
             return stats
-        self._on_host_sync()
-        delta = int(stats["events"]) - events0
+        delta = int(self._read(stats["events"])) - events0
         if delta > 0:
             self.pool.attribute(faulty, delta)
         return stats

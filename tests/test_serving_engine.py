@@ -227,3 +227,36 @@ def test_range_guard_keeps_readouts_finite(model_params, paged_decode):
         rows[name] = eng.metrics()["nonfinite_logit_rows"]
     assert rows["nan_inf"] > 0
     assert rows["default"] == 0
+
+
+# ---------------------------------------------------------- program names
+def test_repair_and_reset_programs_have_stable_names(model_params):
+    """A profile names each program by its jitted function: the repair
+    plan's executables and the pool's page reset carry their own names, so
+    trace readers can tell the scrub from the injection and the reset."""
+    from repro.serving.engine import engine_space
+    from repro.serving.pool import pool_reset_pages
+
+    model, _ = model_params
+    cfg = ServingConfig(page_size=4, n_pages=4, max_batch=1,
+                        max_pages_per_request=2, repair="page")
+    space = engine_space(model)
+    pool = PagedKVPool(model, space, cfg)
+    leaves = tuple(jax.tree.leaves(pool.tree))
+    ids = jnp.zeros((2,), jnp.int32)
+
+    def module(fn, *args):
+        return fn.lower(*args).as_text().split("module @", 1)[1].split()[0]
+
+    pages = space.plan_for(pool.tree, scope="pages", trigger="reactive")
+    tree = space.plan_for(pool.tree, scope="tree", trigger="reactive")
+    inject = space.plan_for(pool.tree, scope="inject", ber=1e-3)
+    ref = space.plan_for(pool.tree, scope="reference")
+    assert module(pages._exec(("pages", 2, False)), leaves, ids,
+                  jnp.asarray(1, jnp.int32)) == "jit_repair_pages"
+    assert module(tree._exec(("tree", False)), leaves) == "jit_repair_tree"
+    assert module(inject._exec(("inject", True)), leaves,
+                  jax.random.PRNGKey(0)) == "jit_inject"
+    assert module(ref._exec(("reference", False)), leaves,
+                  leaves) == "jit_repair_reference"
+    assert module(pool_reset_pages, pool.tree, ids) == "jit_pool_reset_pages"

@@ -172,20 +172,79 @@ def test_desync_wide_interval_token_parity_under_load(model_params):
     assert reps[3]["n_host_syncs"] < reps[1]["n_host_syncs"]
 
 
-def test_metrics_expose_syncs_and_stage_walls(model_params):
+def _host_spans(trace_dir):
+    """``(name, start_ns, end_ns)`` of every event on the host thread that
+    ran the engine (the one holding its ``engine.step`` spans)."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+                   for e in line.events]
+            if any(n == "engine.step" for n, _, _ in evs):
+                return evs
+    raise AssertionError("no engine.step span in the profile")
+
+
+def test_metrics_expose_syncs_and_stage_walls(model_params, tmp_path):
+    """Every stage of a step is a profiler span on the profiler's clock,
+    nested in its ``engine.step``; each blocking readback is one
+    ``engine.readback`` (so the spans and ``n_host_syncs`` agree) and each
+    prompt chunk dispatched one ``engine.prefill_chunk``."""
+    from repro.runtime.config import AutopilotConfig
+
     model, params = model_params
-    eng = Engine(model, params, _cfg())
-    eng.add_request([4, 8, 15], max_new=3)
-    eng.run()
-    m = eng.metrics()
-    assert m["n_host_syncs"] > 0
-    assert m["host_syncs_per_step"] > 0
-    assert m["drain_interval"] == 0
-    assert m["sharded_kernels"] is False
-    walls = m["stage_wall_s"]
-    assert set(walls) == {"admit", "prefill", "decode", "repair", "guard"}
-    assert all(v >= 0.0 for v in walls.values())
-    assert walls["prefill"] > 0.0 and walls["decode"] > 0.0
+    guard = AutopilotConfig(window=2, tolerance=1.0, floor=0.0, patience=1,
+                            cooldown=0, expected=(("default", 0.0),))
+    # fused lockstep; fused with a deferred drain and the guard; gathered
+    fused = Engine(model, params, _cfg())
+    desync = Engine(model, params, _cfg(drain_interval=2, autopilot=guard))
+    gathered = Engine(model, params, _cfg(paged_decode="off"))
+    fused.add_request(list(range(1, 11)), max_new=3)     # chunks 4, 4, 2
+    fused.add_request([4, 8, 15], max_new=3)             # one chunk
+    desync.add_request([5, 6, 7, 8, 9], max_new=4)       # chunks 4, 1
+    gathered.add_request([4, 8, 15], max_new=3)          # one whole prompt
+    engines = (fused, desync, gathered)
+    assert fused._prefill_fn is not None and desync._desync
+    assert gathered._paged_fn is None and desync.guard is not None
+    syncs0 = [e.n_host_syncs for e in engines]
+    jax.profiler.start_trace(str(tmp_path))
+    n_steps = 0
+    for eng in engines:
+        while eng.has_work:
+            eng.step()
+            n_steps += 1
+    jax.profiler.stop_trace()
+    syncs = sum(e.n_host_syncs - s for e, s in zip(engines, syncs0))
+
+    spans = _host_spans(str(tmp_path))
+    names = {n for n, _, _ in spans}
+    assert {"engine.step", "engine.drain", "engine.admit", "engine.prefill",
+            "engine.prefill_chunk", "engine.decode", "engine.repair",
+            "engine.sweep", "engine.guard", "engine.readback",
+            "pool.reset_pages"} <= names
+    steps = [(a, b) for n, a, b in spans if n == "engine.step"]
+    assert len(steps) == n_steps
+    for name, a, b in spans:
+        if name.startswith("engine.") and name != "engine.step":
+            assert any(s <= a and b <= e for s, e in steps), name
+    readbacks = [s for s in spans if s[0] == "engine.readback"]
+    assert syncs > 0 and len(readbacks) == syncs
+    chunks = [s for s in spans if s[0] == "engine.prefill_chunk"]
+    assert len(chunks) == 4 + 2 + 1
+    # the engines' metrics carry the count the spans mirror, and no
+    # host-clock stage timings
+    m = fused.metrics()
+    assert m["n_host_syncs"] > 0 and m["host_syncs_per_step"] > 0
+    assert m["drain_interval"] == 0 and m["sharded_kernels"] is False
+    assert not any("wall" in k for k in m)
 
 
 # -------------------------------------------------- scheduler fairness
