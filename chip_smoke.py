@@ -133,14 +133,15 @@ def check_kernels(
 ) -> dict:
     """Decode (serial and split-K) and chunked prefill at the serving
     widths, on a pool with NaN/Inf lanes parked in pages the requests read
-    (and one in the null page): outputs finite and within bf16 tolerance
-    of the oracle, per-slot fatal counts identical."""
+    (and one in the null page, which no context reads): outputs finite and
+    within bf16 tolerance of the oracle, per-slot fatal counts identical."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.kernels import paged_attention as pa
     from repro.kernels import ref
+    from repro.serving.config import ServingConfig
 
     rng = np.random.default_rng(seed)
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
@@ -163,7 +164,7 @@ def check_kernels(
     pos = rng.integers(page_size, width * page_size, size=batch).astype(np.int32)
     bt = tables(batch, pos)
     # fatal lanes inside read positions of request 0 and 1, one in the null
-    # page (every padded slot DMAs it)
+    # page (padding: never counted)
     last = head_dim - 1
     faults = [
         ("k", bt[0, 0], 3, 0, 5, jnp.nan), ("k", bt[1, 0], 0, 1, last, jnp.nan),
@@ -177,7 +178,10 @@ def check_kernels(
             vp = vp.at[page, layer, slot, head, d].set(val)
     q = jax.random.normal(keys[2], (batch, heads, head_dim), jnp.float32).astype(dtype)
     lay = jnp.int32(layer)
-    splits = width // 2
+    splits = ServingConfig(
+        page_size=page_size, n_pages=rows - 1, max_batch=batch,
+        max_pages_per_request=width,
+    ).resolve_split_k()
     out = {}
 
     def decode(q, kp, vp, bt, pos, lay):

@@ -25,24 +25,31 @@ Layout:
   block_tables  (B, M) int32        per-request page lists, null-padded
   positions     (B) int32           last valid context position (inclusive)
 
-Grid (B, M): request-major, one physical page per inner step.  The page's
-pool row is selected *by the block table* through the k/v BlockSpec index
-maps — the block table is a scalar-prefetch operand, available before the
-kernel body, which is exactly what PrefetchScalarGridSpec exists for.
-Online-softmax state (acc, m, l) lives in scratch across the page axis.
-Null-padded tail slots are masked by position (a request's real pages cover
-positions ``0..pos``; padding covers positions beyond it), but their DMA
-and detection still run: a NaN parked in the null page would otherwise
-poison the context through ``0 * NaN`` in the value contraction — here it
-is repaired in VMEM and *reported*, like any other page.
+Two decode walks share that contract.  A block-table slot ``j`` is *live*
+when its first position ``j * pg`` lies within the request's context
+(positions ``0..pos``); the slots past it are null padding or pages
+allocated ahead.
+
+* Split-K (``paged_attention_splitk_raw``, the serving path once the table
+  is 8 pages wide): grid (B, splits); each cell copies only its live
+  pages from HBM, several pages per double-buffered block, and stops at
+  its last live block.  Null padding and the unallocated tail are never
+  fetched, so a flip there cannot reach a context and nothing detects it.
+* Serial (``paged_attention_raw``): grid (B, M), one physical page per
+  inner step, its pool row selected *by the block table* through the k/v
+  BlockSpec index maps (a scalar-prefetch operand, available before the
+  kernel body).  Every slot is streamed and repaired in VMEM — a NaN
+  parked in the null page would otherwise poison the context through
+  ``0 * NaN`` in the value contraction — but only live slots count.
 
 Outputs: (out (B, H, Dh), slot_counts (B, M) int32, counts int32[8]).
-``slot_counts[b, j]`` is the fatal-lane count of the page visited by block
-slot (b, j) — scatter-added over the block table this becomes the
-``(n_pages,)`` per-page vector the serving repair manager consumes (pages
-visited by several slots, i.e. the null page, accumulate per visit; the
-manager only needs the >0 predicate).  ``counts`` is the shared AT_* event
-layout of ``repair_attention`` so the unified stats routing is identical.
+``slot_counts[b, j]`` is the fatal-lane count of the page read at live
+block slot (b, j), and 0 at every slot past the context — scatter-added
+over the block table this becomes the ``(n_pages,)`` per-page vector the
+serving repair manager consumes (pages read by several slots accumulate
+per read; the manager only needs the >0 predicate).  ``counts`` is the
+shared AT_* event layout of ``repair_attention`` so the unified stats
+routing is identical.
 """
 from __future__ import annotations
 
@@ -52,6 +59,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from . import common
@@ -76,39 +84,17 @@ _PREFILL_VMEM_BYTES = 32 << 20
 # causal comparison fails (tq is hugely negative) and the count gate is off
 NO_SLOT = -(1 << 30)
 
+# keys per block of the split-K walk: one lane-dense score tile of 2 x 128
+# lanes, so a 16-token page pool walks up to 16 pages per copy block
+_SPLITK_BLOCK_KEYS = 256
 
-def _repair_and_count(
-    consts_ref, k_ref, v_ref, slot_ref, counts_ref, slot,
-    *, policy_k: str, constant_k: float, policy_v: str, constant_v: float,
-    gate=None,
+
+def _record_counts(
+    slot_ref, counts_ref, slot, nan_k, inf_k, nan_v, inf_v, gate
 ):
-    """Fused on-read repair of one page's K/V rows (the trap) — shared by
-    every kernel in the paged family.  Per-operand fill selection: each
-    tile repairs with ITS operand's rule fill (row 0 = K, row 1 = V), so a
-    mixed-fill RuleSet compiles into one kernel instead of forcing the
-    gathered fallback.  Accumulates the AT_* event counts and writes the
-    per-page-slot fatal count the reactive repair manager consumes.
-
-    ``gate`` (int32 0/1, default 1) masks the *counting* side only: under
-    the sharded walk a device visits every block-table slot but owns only
-    the pages of its shard — non-owned slots are remapped to a local row
-    whose faults belong to another device, so their detections must not be
-    reported here (the VMEM repair itself is harmless: the slot's scores
-    are fully masked).  Each page is thus counted by exactly one device.
-
-    ``slot`` is the ``(b, j)`` block-table slot this grid step visits.  The
-    constants, the counters and the slot counts all live in SMEM, so every
-    access is one scalar load or store."""
-    if gate is None:
-        gate = jnp.int32(1)
-    k_fixed, nan_k, inf_k = common.repair_tile(
-        k_ref[0, 0], policy=policy_k, constant=constant_k,
-        consts=common.consts_row(consts_ref, 0),
-    )
-    v_fixed, nan_v, inf_v = common.repair_tile(
-        v_ref[0, 0], policy=policy_v, constant=constant_v,
-        consts=common.consts_row(consts_ref, 1),
-    )
+    """Accumulate one page's AT_* events and write its per-page-slot fatal
+    count (``gate`` 0 masks both).  The counters and the slot counts live
+    in SMEM, so every access is one scalar load or store."""
     ev_k = ((nan_k + inf_k) > 0).astype(jnp.int32)
     ev_v = ((nan_v + inf_v) > 0).astype(jnp.int32)
     counts_ref[NAN_K] += gate * nan_k
@@ -119,6 +105,40 @@ def _repair_and_count(
     counts_ref[EV_V] += gate * ev_v
     counts_ref[EV_TOTAL] += gate * ((ev_k + ev_v) > 0).astype(jnp.int32)
     slot_ref[slot] = gate * (nan_k + inf_k + nan_v + inf_v)
+
+
+def _repair_and_count(
+    consts_ref, k_ref, v_ref, slot_ref, counts_ref, slot,
+    *, policy_k: str, constant_k: float, policy_v: str, constant_v: float,
+    gate=None,
+):
+    """Fused on-read repair of one page's K/V rows (the trap) — shared by
+    the serial decode and the prefill kernels.  Per-operand fill selection:
+    each tile repairs with ITS operand's rule fill (row 0 = K, row 1 = V),
+    so a mixed-fill RuleSet compiles into one kernel instead of forcing the
+    gathered fallback.  Accumulates the AT_* event counts and writes the
+    per-page-slot fatal count the reactive repair manager consumes.
+
+    ``gate`` (int32 0/1, default 1) masks the *counting* side only: a slot
+    past the request's context, or (under the sharded walk) one whose page
+    another device owns, is still streamed and repaired here (harmless —
+    its scores are fully masked) but reports nothing, so each page is
+    counted by exactly one device and only where a context reads it.
+
+    ``slot`` is the ``(b, j)`` block-table slot this grid step visits."""
+    if gate is None:
+        gate = jnp.int32(1)
+    k_fixed, nan_k, inf_k = common.repair_tile(
+        k_ref[0, 0], policy=policy_k, constant=constant_k,
+        consts=common.consts_row(consts_ref, 0),
+    )
+    v_fixed, nan_v, inf_v = common.repair_tile(
+        v_ref[0, 0], policy=policy_v, constant=constant_v,
+        consts=common.consts_row(consts_ref, 1),
+    )
+    _record_counts(
+        slot_ref, counts_ref, slot, nan_k, inf_k, nan_v, inf_v, gate
+    )
     return k_fixed, v_fixed
 
 
@@ -178,10 +198,13 @@ def _paged_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
+    # slots past the context are streamed (the walk is M wide) but count
+    # nothing: the split-K walk never reads them, and both report alike
     k_fixed, v_fixed = _repair_and_count(
         consts_ref, k_ref, v_ref, slot_ref, counts_ref, (b, j),
         policy_k=policy_k, constant_k=constant_k,
         policy_v=policy_v, constant_v=constant_v,
+        gate=(j * pg <= pos_ref[b]).astype(jnp.int32),
     )
 
     # ---- online softmax over this page ----
@@ -643,101 +666,295 @@ def paged_prefill(
 
 
 # --------------------------------------------------------------------------
-# Split-K flash decoding: the page walk parallelized across grid cells.
+# Split-K flash decoding: the live page walk, in blocks of pages.
 # --------------------------------------------------------------------------
-def _paged_splitk_kernel(
-    consts_ref,      # int32[2, 8]  detector constants: row 0 K, row 1 V
-    bt_ref,          # int32[B, M]  block tables (also drives the index maps)
-    pos_ref,         # int32[B, M]  last valid position, per block slot
-    layer_ref,       # int32[1]     which L row of the pool leaves
-    q_ref, k_ref, v_ref,
-    o_ref, mo_ref, lo_ref, slot_ref, counts_ref,
-    acc_ref, m_ref, l_ref,
-    *, sm_scale: float,
-    policy_k: str, constant_k: float, policy_v: str, constant_v: float,
-    pg: int, n_kv: int, group: int, ns: int,
-):
-    b, g, jj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    step = (b * pl.num_programs(1) + g) * pl.num_programs(2) + jj
+def _splitk_block_pages(ns: int, pg: int) -> int:
+    """Pages per block of the split-K walk: the largest divisor of the
+    split's slot count ``ns`` whose keys fill at most one lane-dense score
+    tile of ``_SPLITK_BLOCK_KEYS``."""
+    fit = [d for d in range(1, ns + 1)
+           if ns % d == 0 and d * pg <= _SPLITK_BLOCK_KEYS]
+    return max(fit, default=1)
 
-    @pl.when(step == 0)
+
+def _splitk_live_blocks(pos_slot, pg: int, splits: int, bp: int):
+    """(B, splits) int32 trip counts of the split-K walk: per split, the
+    number of ``bp``-page blocks up to and including its last live one.  A
+    slot ``j`` is live when its first position ``j * pg`` lies within its
+    bound (``pos_slot``; -1 on slots another device owns)."""
+    B, M = pos_slot.shape
+    nb = M // splits // bp
+    first = jnp.arange(M, dtype=jnp.int32) * pg
+    live = (first[None, :] <= pos_slot).reshape(B, splits, nb, bp)
+    idx = jnp.arange(1, nb + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(live.any(-1), idx, 0), axis=-1).astype(jnp.int32)
+
+
+def _fill_bits(policy: str, constant: float, dtype) -> int:
+    """The bit pattern a value-independent fill writes into a repaired lane
+    of ``dtype`` — the split-K walk repairs whole blocks of raw words."""
+    value = {
+        "zero": 0.0,
+        "constant": constant,
+        "clamp_finite_max": float(jnp.finfo(dtype).max),
+    }.get(policy)
+    if value is None:
+        raise ValueError(
+            f"the split-K walk fills with zero, constant or clamp_finite_max, "
+            f"not {policy!r}"
+        )
+    bits = np.asarray(value, jnp.dtype(dtype))
+    return int(bits.view(np.uint16 if bits.itemsize == 2 else np.uint32))
+
+
+def _repair_heads(w, consts, fill: int, width: int):
+    """The trap on a block of raw 32-bit words ``w``: each ``width``-bit
+    lane is detected and, where fatal, replaced by the ``fill`` bits.  A
+    16-bit pool packs two KV heads per word (the lower head in the low
+    half); each half is moved to the high half of a word, where its
+    bfloat16 bits are the float32 of the same value, and detected there
+    with the detector constants shifted alike.  Returns, per head in the
+    words, ``(value f32, NaN-bucket mask, non-NaN-bucket mask)``
+    (``common.masks_from_consts``)."""
+    if width == 32:
+        halves, shift = [w], 0
+    else:
+        halves, shift = [w << 16, w & jnp.uint32(0xFFFF0000)], 16
+    # masks, range threshold and bit pattern move with the lane; the
+    # flags (2) and the row bound (6, unused here) do not
+    consts = tuple(
+        c << shift if k in (0, 1, 3, 4, 5) else c for k, c in enumerate(consts)
+    )
+    out = []
+    for x in halves:
+        nan_m, inf_m = common.masks_from_consts(x, consts)
+        fixed = jnp.where(nan_m | inf_m, jnp.uint32(fill << shift), x)
+        out.append(
+            (jax.lax.bitcast_convert_type(fixed, jnp.float32), nan_m, inf_m)
+        )
+    return out
+
+
+def _paged_splitk_kernel(
+    consts_ref,      # int32[2, 8]      detector constants: row 0 K, row 1 V
+    bt_ref,          # int32[B, M]      block tables (page ids of the copies)
+    pos_ref,         # int32[B, M]      last valid position, per block slot
+    nblk_ref,        # int32[B, S]      live blocks to walk, per split
+    layer_ref,       # int32[1]         which L row of the pool leaves
+    q_ref, k_hbm, v_hbm,
+    o_ref, mo_ref, lo_ref, slot_ref, counts_ref,
+    k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
+    *, sm_scale: float, fill_k: int, fill_v: int,
+    pg: int, n_kv: int, group: int, ns: int, bp: int,
+):
+    from jax.experimental.pallas import tpu as pltpu  # local: CPU-safe import
+
+    b, g = pl.program_id(0), pl.program_id(1)
+    lay = layer_ref[0]
+    base = g * ns                        # first block-table slot of the split
+    n_blocks = nblk_ref[b, g]
+    H = n_kv * group
+    N = bp * pg
+    Dh = q_ref.shape[-1]
+
+    @pl.when((b == 0) & (g == 0))
     def _init_counts():
         common.zero_counts(counts_ref, 8)
 
-    @pl.when(jj == 0)
-    def _init_state():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    # slots the walk never reads report 0; read pages overwrite theirs
+    # (a few scalar stores per loop step)
+    u = max(d for d in range(1, 9) if ns % d == 0)
 
-    # per-SLOT position bound: on a single device every slot of request b
-    # carries pos[b]; under the sharded walk non-owned slots carry -1 —
-    # every key position fails `t <= bound` and the count gate is off
-    bound = pos_ref[b, g * ns + jj]
-    k_fixed, v_fixed = _repair_and_count(
-        consts_ref, k_ref, v_ref, slot_ref, counts_ref, (b, g * ns + jj),
-        policy_k=policy_k, constant_k=constant_k,
-        policy_v=policy_v, constant_v=constant_v,
-        gate=(bound >= 0).astype(jnp.int32),
-    )
+    def _zero_slots(t, carry):
+        for k in range(u):
+            slot_ref[b, base + t * u + k] = jnp.int32(0)
+        return carry
 
-    # ---- online softmax over this split's slice of the page walk ----
-    H = n_kv * group
-    q = q_ref[0].astype(jnp.float32).reshape(n_kv, group, q_ref.shape[-1])
-    kb = jnp.moveaxis(k_fixed.astype(jnp.float32), 1, 0)     # (Kh, pg, Dh)
-    s = jax.lax.dot_general(
-        q, kb, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * sm_scale                                             # (Kh, G, pg)
-    t = (g * ns + jj) * pg + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, pg), 2
-    )
-    s = jnp.where(t <= bound, s, NEG_INF)
-    s2 = s.reshape(H, pg)
+    jax.lax.fori_loop(0, ns // u, _zero_slots, 0)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-    m_prev = m_ref[:, 0]                                     # (H,)
-    m_new = jnp.maximum(m_prev, jnp.max(s2, axis=-1))
-    # null-tail guard: unlike the serial walk (whose slot 0 always holds a
-    # valid position), a split can land on NOTHING but null padding.  Its
-    # running max then never leaves NEG_INF, and a bare exp(s - m) would be
-    # exp(0) = 1 per fill lane — fill values leaking probability mass into
-    # the merge.  Masking p on score validity keeps such splits at exactly
-    # (m, l, acc) = (-inf, 0, 0), which the LSE merge drops.
-    p = jnp.where(
-        s2 > NEG_INF * 0.5, jnp.exp(s2 - m_new[:, None]), 0.0
-    )                                                        # (H, pg)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-    vb = jnp.moveaxis(v_fixed, 1, 0)                         # (Kh, pg, Dh)
-    pv = jax.lax.dot_general(
-        p.reshape(n_kv, group, pg).astype(v_fixed.dtype), vb,
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                                        # (Kh, G, Dh)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv.reshape(acc_ref.shape)
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+    def live(j):
+        # per-SLOT bound: on a single device every slot of request b
+        # carries pos[b]; under the sharded walk non-owned slots carry -1
+        return j * pg <= pos_ref[b, j]
 
-    @pl.when(jj == ns - 1)
-    def _flush():
-        # raw partials — normalization happens in the LSE merge stage
-        o_ref[0, 0] = acc_ref[...]
-        mo_ref[0, 0, 0] = m_ref[:, 0]
-        lo_ref[0, 0, 0] = l_ref[:, 0]
+    def copies(i, buf, p):
+        page = bt_ref[b, base + i * bp + p]
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[page, lay], k_buf.at[buf, p], sems.at[0, buf]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[page, lay], v_buf.at[buf, p], sems.at[1, buf]
+            ),
+        )
+
+    def each_copy(i, buf, op, dead=None):
+        """``op`` ("start" or "wait") on the K and V copies of each live
+        page of block i — one branch when the whole block is live, else
+        one per slot, with ``dead(p)`` on the rest."""
+        slots = [base + i * bp + p for p in range(bp)]
+        full = functools.reduce(jnp.logical_and, [live(j) for j in slots])
+
+        def act(p):
+            for cp in copies(i, buf, p):
+                getattr(cp, op)()
+
+        @pl.when(full)
+        def _all():
+            for p in range(bp):
+                act(p)
+
+        @pl.when(jnp.logical_not(full))
+        def _some():
+            for p, j in enumerate(slots):
+                pl.when(live(j))(functools.partial(act, p))
+                if dead is not None:
+                    pl.when(jnp.logical_not(live(j)))(
+                        functools.partial(dead, p)
+                    )
+
+    def start(i, buf):
+        """Issue block i's page copies into buffer ``buf``, all at once.
+        A dead slot is not fetched: its buffer page is zeroed instead, so
+        stale bits never meet the value contraction."""
+        def clear(p):
+            k_buf[buf, p] = jnp.zeros(k_buf.shape[2:], k_buf.dtype)
+            v_buf[buf, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+
+        each_copy(i, buf, "start", clear)
+
+    def wait(i, buf):
+        each_copy(i, buf, "wait")
+
+    width = jnp.dtype(k_buf.dtype).itemsize * 8              # 16 or 32
+    pack = 32 // width                   # KV heads per 32-bit word
+    stride = n_kv // pack                # word rows per key position
+
+    def heads(ref, buf, consts, fill):
+        """One operand's block, repaired in VMEM: ``(value, nan, inf)`` per
+        KV head.  The block is read as dense 32-bit words — a 16-bit pool's
+        (Kh, Dh) page rows pair heads 2r, 2r+1 in one word — so each load
+        fills whole vregs where (Kh, Dh) tiles would pad to the sublane
+        count."""
+        wref = ref.at[buf].bitcast(jnp.uint32).reshape(N * stride, Dh)
+        out = []
+        for r in range(stride):
+            w = wref[pl.ds(r, N, stride=stride)] if stride > 1 else wref[...]
+            out += _repair_heads(w, consts, fill, width)
+        return out
+
+    def attend(i, buf):
+        j0 = base + i * bp
+        k_heads = heads(k_buf, buf, common.consts_row(consts_ref, 0), fill_k)
+        v_heads = heads(v_buf, buf, common.consts_row(consts_ref, 1), fill_v)
+        fatal = functools.reduce(
+            jnp.logical_or, [n | f for _, n, f in k_heads + v_heads]
+        )
+
+        @pl.when(jnp.sum(fatal.astype(jnp.int32)) > 0)
+        def _account():
+            # rare path: a lane fired somewhere in the block — count per page
+            def page(hs, p, kind):
+                return sum(
+                    jnp.sum(h[kind][p * pg:(p + 1) * pg].astype(jnp.int32))
+                    for h in hs
+                )
+
+            for p in range(bp):
+                _record_counts(
+                    slot_ref, counts_ref, (b, j0 + p),
+                    page(k_heads, p, 1), page(k_heads, p, 2),
+                    page(v_heads, p, 1), page(v_heads, p, 2),
+                    live(j0 + p).astype(jnp.int32),
+                )
+
+        # ---- online softmax over the block's N = bp * pg keys ----
+        # bf16 products are exact in f32: a bf16 pool and query contract
+        # in bf16 with f32 accumulation, anything else in f32
+        cdt = k_buf.dtype if q_ref.dtype == k_buf.dtype else jnp.float32
+        q = q_ref[0].reshape(n_kv, group, Dh).astype(cdt)
+        s = jnp.stack([
+            jax.lax.dot_general(
+                q[h], k_heads[h][0].astype(cdt), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for h in range(n_kv)
+        ]) * sm_scale                                        # (Kh, G, N)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, N), 2)
+        bound = jnp.full((1, 1, N), pos_ref[b, j0], jnp.int32)
+        for p in range(1, bp):
+            bound = jnp.where(lane >= p * pg, pos_ref[b, j0 + p], bound)
+        s = jnp.where(j0 * pg + lane <= bound, s, NEG_INF)
+        s2 = s.reshape(H, N)
+
+        m_prev = m_ref[:, 0]                                 # (H,)
+        m_new = jnp.maximum(m_prev, jnp.max(s2, axis=-1))
+        # empty-walk guard: a split whose live keys are all masked (the
+        # sharded walk's non-owned slots) keeps (m, l, acc) = (-inf, 0, 0)
+        # exactly — a bare exp(s - m) would be exp(0) = 1 per masked lane
+        p_ = jnp.where(
+            s2 > NEG_INF * 0.5, jnp.exp(s2 - m_new[:, None]), 0.0
+        )                                                    # (H, N)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, 0] * alpha + jnp.sum(p_, axis=-1)
+        # softmax weights quantize to the cache dtype before the value
+        # contraction, as in the serial walk and the gathered path
+        p3 = p_.reshape(n_kv, group, N).astype(v_buf.dtype)
+        pv = jnp.stack([
+            jax.lax.dot_general(
+                p3[h], v_heads[h][0].astype(v_buf.dtype),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for h in range(n_kv)
+        ])                                                   # (Kh, G, Dh)
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv.reshape(H, Dh)
+        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+
+    # ---- the live blocks of this split, double-buffered ----
+    @pl.when(n_blocks > 0)
+    def _prime():
+        start(0, 0)
+
+    def body(i, carry):
+        buf = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blocks)
+        def _prefetch():
+            start(i + 1, 1 - buf)
+
+        wait(i, buf)
+        attend(i, buf)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, body, 0)
+
+    # raw partials — normalization happens in the LSE merge stage
+    o_ref[0, 0] = acc_ref[...]
+    mo_ref[0, 0, 0] = m_ref[:, 0]
+    lo_ref[0, 0, 0] = l_ref[:, 0]
 
 
 def _splitk_partials(
     q, k_pages, v_pages, block_tables, pos_slot, layer,
     *, splits, consts, policy_k, constant_k, policy_v, constant_v, interpret,
 ):
-    """Unnormalized split-K decode partials over the block-table walk.
+    """Unnormalized split-K decode partials over the live block-table walk.
 
     ``pos_slot`` is (B, M) int32 — the inclusive position bound carried
     *per block slot*.  On a single device every slot of request ``b`` holds
-    ``positions[b]``; under the sharded walk non-owned slots hold ``-1``
-    (fully masked, counts gated).  Returns ``(o_part (B, splits, H, Dh)
-    f32, m_part (B, splits, H) f32, l_part (B, splits, H) f32,
-    slot_counts, counts)``.
+    ``positions[b]``; under the sharded walk non-owned slots hold ``-1``.
+    A slot is read only when its first position lies within its bound:
+    each grid cell ``(b, split)`` copies its live slots' pages from HBM in
+    blocks of ``bp`` (``_splitk_block_pages``), double-buffered, and walks
+    no further than its last live block.  Unread slots are neither fetched
+    nor detected and read 0 in ``slot_counts``.  Returns ``(o_part (B,
+    splits, H, Dh) f32, m_part (B, splits, H) f32, l_part (B, splits, H)
+    f32, slot_counts, counts)``.
     """
     B, H, Dh = q.shape
     P, L, pg, Kh, _ = k_pages.shape
@@ -748,45 +965,43 @@ def _splitk_partials(
     assert splits >= 1 and M % splits == 0, (
         f"splits={splits} must divide the block-table width M={M}"
     )
+    assert k_pages.dtype in (jnp.float32, jnp.bfloat16), (
+        f"the split-K walk reads float32 or bfloat16 pools, not {k_pages.dtype}"
+    )
+    assert Kh % (4 // k_pages.dtype.itemsize) == 0, (
+        f"a 16-bit pool packs KV heads in pairs: Kh={Kh} must be even"
+    )
     ns = M // splits
+    bp = _splitk_block_pages(ns, pg)
     sm_scale = 1.0 / math.sqrt(Dh)
+    pos_slot = jnp.asarray(pos_slot, jnp.int32)
+    n_blocks = _splitk_live_blocks(pos_slot, pg, splits, bp)
 
     from jax.experimental.pallas import tpu as pltpu  # local: CPU-safe import
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # detector consts, block tables, positions, layer
-        grid=(B, splits, ns),
+        # detector consts, block tables, positions, live blocks, layer
+        num_scalar_prefetch=5,
+        grid=(B, splits),
         in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda b, g, jj, c, bt, pos, lay: (b, 0, 0)),
-            pl.BlockSpec(
-                (1, 1, pg, Kh, Dh),
-                lambda b, g, jj, c, bt, pos, lay: (
-                    bt[b, g * ns + jj], lay[0], 0, 0, 0
-                ),
-            ),
-            pl.BlockSpec(
-                (1, 1, pg, Kh, Dh),
-                lambda b, g, jj, c, bt, pos, lay: (
-                    bt[b, g * ns + jj], lay[0], 0, 0, 0
-                ),
-            ),
+            pl.BlockSpec((1, H, Dh), lambda b, g, *_: (b, 0, 0)),
+            # the pool leaves stay in HBM: the kernel copies live pages
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec(
-                (1, 1, H, Dh), lambda b, g, jj, c, bt, pos, lay: (b, g, 0, 0)
-            ),
+            pl.BlockSpec((1, 1, H, Dh), lambda b, g, *_: (b, g, 0, 0)),
             # (B, splits, 1, H): a unit second-minor axis keeps the
             # block's last two dims equal to the array's (TPU tiling)
-            pl.BlockSpec(
-                (1, 1, 1, H), lambda b, g, jj, c, bt, pos, lay: (b, g, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, H), lambda b, g, jj, c, bt, pos, lay: (b, g, 0, 0)
-            ),
+            pl.BlockSpec((1, 1, 1, H), lambda b, g, *_: (b, g, 0, 0)),
+            pl.BlockSpec((1, 1, 1, H), lambda b, g, *_: (b, g, 0, 0)),
             common.smem_spec(),     # slot counts (B, M)
             common.smem_spec(),     # counts int32[8]
         ],
         scratch_shapes=[
+            pltpu.VMEM((2, bp, pg, Kh, Dh), k_pages.dtype),
+            pltpu.VMEM((2, bp, pg, Kh, Dh), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),     # (K|V, buffer)
             pltpu.VMEM((H, Dh), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
@@ -796,14 +1011,13 @@ def _splitk_partials(
         functools.partial(
             _paged_splitk_kernel,
             sm_scale=sm_scale,
-            policy_k=policy_k,
-            constant_k=constant_k,
-            policy_v=policy_v,
-            constant_v=constant_v,
+            fill_k=_fill_bits(policy_k, constant_k, k_pages.dtype),
+            fill_v=_fill_bits(policy_v, constant_v, v_pages.dtype),
             pg=pg,
             n_kv=Kh,
             group=group,
             ns=ns,
+            bp=bp,
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -817,7 +1031,8 @@ def _splitk_partials(
     )(
         consts,
         jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(pos_slot, jnp.int32),
+        pos_slot,
+        n_blocks,
         jnp.asarray(layer, jnp.int32).reshape(1),
         q, k_pages, v_pages,
     )
@@ -852,16 +1067,19 @@ def paged_attention_splitk_raw(
     policy_v: Optional[str] = None,
     constant_v: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Split-K paged decode: flash-decoding for the block-table page walk.
+    """Split-K paged decode: flash-decoding over the live block-table walk.
 
     The M block-table slots are partitioned into ``splits`` contiguous
     groups, each walked by its own grid cell into an unnormalized partial
     ``(acc, m, l)``; a log-sum-exp merge reduce stage combines the partials
-    (colossal-ai ``flash_decoding.py``'s mid_o/mid_o_lse staging).  Splits
-    whose slice is pure null padding carry ``m = -inf`` and zero weight into
-    the merge — see the null-tail guard in the kernel body.  Detection and
-    per-page counts are identical to the serial kernel: every slot is
-    visited exactly once, so ``slot_counts`` is bit-identical.  Returns
+    (colossal-ai ``flash_decoding.py``'s mid_o/mid_o_lse staging).  A cell
+    copies only the pages of its live slots (first position ``<= pos``),
+    in blocks of several pages, so its work follows the request's context
+    and not the table's width.  Null padding and pages past the position
+    are neither fetched nor detected: ``slot_counts`` is 0 there.  Splits
+    with no live slot carry ``m = -inf`` and zero weight into the merge.
+    Every read page is repaired in VMEM and counted exactly as the serial
+    kernel does, so the two walks' ``slot_counts`` agree.  Returns
     ``(out (B, H, Dh), slot_counts (B, M) int32, counts int32[8])``.
     """
     if interpret is None:
@@ -938,9 +1156,10 @@ def paged_attention_splitk(
 
 def _owned_remap(block_tables, lo, p_local):
     """Ownership mask + shard-local row remap for one device's page range.
-    Non-owned slots are remapped to local row 0: their DMA and VMEM repair
-    still run (harmless — scores fully masked, counts gated), which keeps
-    the grid walk shape identical on every device."""
+    Non-owned slots are remapped to local row 0 with a sentinel bound: the
+    decode walk never fetches them, and the prefill walk streams them
+    (harmless — scores fully masked, counts gated), which keeps its grid
+    shape identical on every device."""
     owned = (block_tables >= lo) & (block_tables < lo + p_local)
     return owned, jnp.where(owned, block_tables - lo, 0)
 
@@ -996,9 +1215,8 @@ def paged_attention_sharded(
 
     Each device walks the full (B, M) block table but attends only to the
     slots whose page lives in its shard (non-owned slots: position bound
-    ``-1`` → fully masked, counts gated off, local row 0 DMA'd as a
-    placeholder).  ``splits > 1`` composes split-K *within* each device's
-    walk, yielding ``nd × splits`` partials.  Counts are psum'd (each slot
+    ``-1`` → never fetched, counted 0).  ``splits > 1`` composes split-K
+    *within* each device's walk, yielding ``nd × splits`` partials.  Counts are psum'd (each slot
     counted exactly once, bit-identical to the serial kernel); the output
     merges each split's device partials, then the splits (``_shard_merge``;
     bit-identical to ``paged_attention_shard_ref``, and to the split-K

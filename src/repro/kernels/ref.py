@@ -165,6 +165,12 @@ def _repair_paged_rows(rows, detector, policy, constant, include_inf):
     return fixed, n_fatal                                      # (B, M)
 
 
+def _live_slots(M: int, pg: int, pos):
+    """(B, M) bool: block-table slots whose first position lies within the
+    request's context — the slots a decode walk reads and counts."""
+    return jnp.arange(M)[None, :] * pg <= pos[:, None]
+
+
 def paged_attention_ref(
     q,                 # (B, H, Dh)
     k_pages,           # (P, pg, Kh, Dh) or (P, L, pg, Kh, Dh)
@@ -212,7 +218,8 @@ def paged_attention_ref(
     fv, cnt_v = _repair_paged_rows(
         v_rows, detector_v, policy_v, constant_v, include_inf
     )
-    slot_counts = cnt_k + cnt_v
+    # slots past the context report nothing (the walk masks their counts)
+    slot_counts = jnp.where(_live_slots(M, pg, pos), cnt_k + cnt_v, 0)
 
     T = M * pg
     fk = fk.reshape(B, T, Kh, Dh)
@@ -320,8 +327,9 @@ def paged_splitk_ref(
     """Oracle of kernels.paged_attention_splitk: per-split softmax partials
     merged by log-sum-exp, with the null-tail guard made explicit — a split
     whose slice holds no valid position carries ``(m, l) = (-inf, 0)`` and
-    zero weight into the merge, never its fill values.  Returns
-    ``(out (B, H, Dh), slot_counts (B, M))``."""
+    zero weight into the merge, never its fill values.  Slots past the
+    context are never read: they count 0 and contribute nothing, whatever
+    their bits.  Returns ``(out (B, H, Dh), slot_counts (B, M))``."""
     if k_pages.ndim == 4:
         k_pages = k_pages[:, None]
         v_pages = v_pages[:, None]
@@ -344,7 +352,11 @@ def paged_splitk_ref(
     fv, cnt_v = _repair_paged_rows(
         v_pages[bt, layer], detector_v, policy_v, constant_v, include_inf
     )
-    slot_counts = cnt_k + cnt_v
+    live = _live_slots(M, pg, pos)                             # (B, M)
+    slot_counts = jnp.where(live, cnt_k + cnt_v, 0)
+    unread = ~live[:, :, None, None, None]
+    fk = jnp.where(unread, jnp.zeros((), fk.dtype), fk)
+    fv = jnp.where(unread, jnp.zeros((), fv.dtype), fv)
 
     # (B, splits, ns*pg, Kh, Dh): each split sees its contiguous page slice
     fk = fk.reshape(B, splits, ns * pg, Kh, Dh)

@@ -20,6 +20,12 @@ _SWAP_POLICIES = ("swap", "recompute")
 # is at least this many pages wide (below it the serial walk wins — the
 # merge stage costs more than it saves)
 _SPLIT_K_MIN_PAGES = 8
+# ... into at most this many splits: the split-K walk reads only live
+# pages, in blocks of up to 256 keys, so on one TensorCore more splits
+# add grid cells and merge work and no parallelism (a TPU v5e sweep at
+# the chat cell's batch: 1 to 5 splits within a few percent, 10 and up
+# slower; PERF.md §6)
+_SPLIT_K_AUTO_MAX = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,7 +80,7 @@ class ServingConfig:
                              0 — auto: split the page walk once the block
                                  table is >= 8 pages wide, into the largest
                                  divisor of ``max_pages_per_request`` that
-                                 keeps >= 2 pages per split
+                                 is <= 4
                              1 — always serial (comparison arm)
                              N — split into (the largest divisor of the
                                  block-table width <=) N grid cells
@@ -233,5 +239,5 @@ class ServingConfig:
         elif M < _SPLIT_K_MIN_PAGES:
             return 1
         else:
-            want = M // 2                 # auto: >= 2 pages per split
+            want = _SPLIT_K_AUTO_MAX
         return max(d for d in range(1, want + 1) if M % d == 0)

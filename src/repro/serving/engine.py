@@ -270,6 +270,10 @@ class Engine:
         # observation counters the hot path reports through (must exist
         # before any helper that syncs is first called)
         self.n_host_syncs = 0
+        # decode walk coverage: the context pages of every decode batch's
+        # requests, and the B x M block-table slots those batches carried
+        self.decode_pages_walked = 0
+        self.decode_page_slots = 0
         # device-local sharded hot path: engaged only when the pool's page
         # axis is genuinely sharded over exactly one mesh axis (divisible
         # row count) — otherwise the single-device kernel walk stays
@@ -850,7 +854,9 @@ class Engine:
     def _decode_batch(
         self, reqs: List[Request]
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """The static-shape decode batch: block tables, tokens, positions."""
+        """The static-shape decode batch: block tables, tokens, positions.
+        Counts the pages the decode walk reads (a request's positions
+        ``0..pos``) against the table slots it carries."""
         B, M = self.cfg.max_batch, self.cfg.max_pages_per_request
         bt = np.full((B, M), self.pool.null_page, np.int32)
         tokens = np.zeros((B, 1), np.int32)
@@ -859,6 +865,8 @@ class Engine:
             bt[req.slot] = self.pool.block_table(req.pages)
             tokens[req.slot, 0] = req.last_token
             pos[req.slot] = req.pos
+            self.decode_pages_walked += self.cfg.pages_for(req.pos + 1)
+        self.decode_page_slots += B * M
         return bt, tokens, pos
 
     def _emit(self, reqs, out, rows, emitted, slots=None) -> None:
@@ -984,6 +992,8 @@ class Engine:
             "tokens_emitted": self.tokens_emitted,
             "n_host_syncs": self.n_host_syncs,
             "host_syncs_per_step": self.n_host_syncs / steps,
+            "decode_pages_walked": self.decode_pages_walked,
+            "decode_page_slots": self.decode_page_slots,
             "drain_interval": self.cfg.drain_interval,
             "sharded_kernels": self._kernel_shard is not None,
             "prefill_tokens_saved": self.prefill_tokens_saved,

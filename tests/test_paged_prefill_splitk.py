@@ -281,6 +281,136 @@ def test_splitk_ragged_null_tail_regression():
     np.testing.assert_array_equal(np.asarray(slot_k), ref_pages)
 
 
+# live-page walk: pages of 16 tokens, 40-slot tables, so splits of 1, 2, 4
+# and 5 walk blocks of 10, 10, 10 and 8 pages
+_LP_PG, _LP_M = 16, 40
+
+
+def _live_pool(key, *, Dh=16):
+    """Four requests over a 40-slot table: 21 live pages of 24 allocated
+    (a multiple of no block size here), one single-token request, an idle
+    slot (all null, position 0) and a full table, plus one page no table
+    holds.  Returns the pool, the tables, the positions, the spare page
+    and the null page."""
+    counts = (24, 1, 0, _LP_M)
+    P = sum(counts) + 2
+    spare, null = P - 2, P - 1
+    k_pages, v_pages = _pool(key, P=P, L=2, pg=_LP_PG, Dh=Dh)
+    bt = np.full((4, _LP_M), null, np.int32)
+    nxt = 0
+    for b, n in enumerate(counts):
+        bt[b, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    pos = np.asarray([20 * _LP_PG + 5, 0, 0, _LP_M * _LP_PG - 1], np.int32)
+    return k_pages, v_pages, jnp.asarray(bt), jnp.asarray(pos), spare, null
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("splits", [1, 2, 4, 5])
+def test_splitk_live_walk_matches_oracle_over_ragged_contexts(splits, dtype):
+    """The live-page walk against the independent oracle: ragged contexts
+    whose live pages do not fill their last block, a one-token request, an
+    idle slot and a full table, at several split counts — values allclose,
+    per-slot counts exact, and the live walk really blocks several pages.
+    A bfloat16 pool is read as words holding two heads' lanes each."""
+    key = jax.random.PRNGKey(21)
+    k_pages, v_pages, bt, pos, _, _ = _live_pool(key)
+    assert pa._splitk_block_pages(_LP_M // splits, _LP_PG) > 1
+    k_pages = k_pages.at[int(bt[0, 17]), 1, 3, 1, 2].set(jnp.nan)
+    v_pages = v_pages.at[int(bt[3, 39]), 1, 15, 0, 7].set(jnp.inf)
+    k_pages, v_pages = k_pages.astype(dtype), v_pages.astype(dtype)
+    q = jax.random.normal(
+        jax.random.fold_in(key, 1), (4, 4, 16), jnp.float32
+    ).astype(dtype)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+
+    out, slot, counts = pa.paged_attention_splitk_raw(
+        q, k_pages, v_pages, bt, pos, jnp.int32(1), splits=splits,
+        policy="zero",
+    )
+    ref_out, ref_slot = ref.paged_splitk_ref(
+        q, k_pages, v_pages, bt, pos, splits=splits, layer=1, policy="zero",
+    )
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref_out, np.float32),
+        atol=tol, rtol=tol,
+    )
+    np.testing.assert_array_equal(np.asarray(slot), np.asarray(ref_slot))
+    assert int(slot[0, 17]) == 1 and int(slot[3, 39]) == 1
+    assert int(counts[pa.EV_TOTAL]) == 2
+    # the one-token request attends to exactly its one key
+    v0 = np.asarray(v_pages[int(bt[1, 0]), 1, 0], np.float32)  # (Kh, Dh)
+    np.testing.assert_array_equal(
+        np.asarray(out[1], np.float32), np.repeat(v0, 2, axis=0)
+    )
+
+
+@pytest.mark.parametrize("detect", [True, False])
+def test_splitk_never_reads_past_the_context(detect):
+    """NaN parked in the null page and in an allocated page past the
+    request's position: neither is read, so the output stays finite even
+    with detection off (an unrepaired NaN that were read would poison the
+    context through 0 * NaN), and both slots count 0."""
+    key = jax.random.PRNGKey(22)
+    k_pages, v_pages, bt, pos, spare, null = _live_pool(key)
+    # allocated past request 0's end, in the block of its last live page
+    bt = bt.at[0, 21].set(spare)
+    k_pages = k_pages.at[null].set(jnp.nan).at[spare].set(jnp.nan)
+    v_pages = v_pages.at[null].set(jnp.nan).at[spare].set(jnp.nan)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (4, 4, 16), jnp.float32)
+    det = "default" if detect else None
+
+    out, slot, counts = pa.paged_attention_splitk_raw(
+        q, k_pages, v_pages, bt, pos, jnp.int32(0), splits=2,
+        detector_k=det, detector_v=det,
+    )
+    # the idle slot (b = 2, position 0) reads the null page's slot 0
+    live_rows = [0, 1, 3]
+    assert bool(jnp.isfinite(out[jnp.asarray(live_rows)]).all())
+    slot = np.asarray(slot)
+    assert (slot[0, 21:] == 0).all()
+    assert (slot[1, 1:] == 0).all() and (slot[2, 1:] == 0).all()
+    assert int(counts[pa.EV_TOTAL]) == (1 if detect else 0)
+    assert slot.sum() == (int(slot[2, 0]) if detect else 0)
+    ref_out, ref_slot = ref.paged_splitk_ref(
+        q, k_pages, v_pages, bt, pos, splits=2, layer=0,
+        detector_k=det, detector_v=det,
+    )
+    np.testing.assert_array_equal(slot, np.asarray(ref_slot))
+    np.testing.assert_allclose(
+        np.asarray(out)[live_rows], np.asarray(ref_out)[live_rows],
+        atol=1e-5, rtol=1e-5,
+    )
+
+
+def test_splitk_repairs_live_page_in_vmem_and_counts_its_slot():
+    """A NaN in a live page (inside the context, in the middle of a block)
+    is repaired on read — the output equals the walk over a pool whose lane
+    was already zero — and counted at its exact (b, slot), nowhere else."""
+    key = jax.random.PRNGKey(23)
+    k_pages, v_pages, bt, pos, _, _ = _live_pool(key)
+    page = int(bt[3, 13])
+    q = jax.random.normal(jax.random.fold_in(key, 1), (4, 4, 16), jnp.float32)
+    clean_k = k_pages.at[page, 0, 6, 0, 9].set(0.0)
+    clean_v = v_pages.at[page, 0, 2, 1, 4].set(0.0)
+    bad_k = k_pages.at[page, 0, 6, 0, 9].set(jnp.nan)
+    bad_v = v_pages.at[page, 0, 2, 1, 4].set(-jnp.inf)
+
+    want, _, _ = pa.paged_attention_splitk_raw(
+        q, clean_k, clean_v, bt, pos, jnp.int32(0), splits=4,
+    )
+    got, slot, counts = pa.paged_attention_splitk_raw(
+        q, bad_k, bad_v, bt, pos, jnp.int32(0), splits=4,
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    expect = np.zeros((4, _LP_M), np.int32)
+    expect[3, 13] = 2
+    np.testing.assert_array_equal(np.asarray(slot), expect)
+    assert int(counts[pa.NAN_K]) == 1 and int(counts[pa.INF_V]) == 1
+    assert int(counts[pa.EV_K]) == 1 and int(counts[pa.EV_V]) == 1
+    assert int(counts[pa.EV_TOTAL]) == 1
+
+
 # ------------------------------------------------------------------ engine
 @pytest.fixture(scope="module")
 def model_params():
@@ -419,6 +549,34 @@ def test_splitk_engine_parity_under_flips(model_params):
     assert split.pool.n_gathers == 0 and split.pool.n_scatters == 0
 
 
+def test_decode_walk_counters(model_params):
+    """``decode_pages_walked`` sums each decoding request's page count over
+    the decode steps; ``decode_page_slots`` is steps x B x M — counted on
+    the host from the batch the engine builds anyway."""
+    model, params = model_params
+    eng = Engine(model, params, ServingConfig(
+        page_size=4, n_pages=12, max_batch=2, max_pages_per_request=8,
+    ))
+    prompt = jax.random.randint(jax.random.PRNGKey(9), (14,), 1, 96)
+    eng.add_request(prompt, max_new=6)
+    eng.add_request([4, 17, 2], max_new=4)
+    want = {"pages": 0, "steps": 0}
+    build = eng._decode_batch
+
+    def spy(reqs):
+        want["pages"] += sum(len(r.pages) for r in reqs)
+        want["steps"] += 1
+        return build(reqs)
+
+    eng._decode_batch = spy
+    eng.run()
+    m = eng.metrics()
+    assert want["steps"] > 0
+    assert m["decode_pages_walked"] == want["pages"]
+    assert m["decode_page_slots"] == want["steps"] * 2 * 8
+    assert 0 < m["decode_pages_walked"] < m["decode_page_slots"]
+
+
 def test_fatal_pages_probe_is_deprecated(model_params):
     """Satellite: the probe survives only as a compat shim — calling it
     warns, and a default fused engine run never triggers it."""
@@ -435,7 +593,10 @@ def test_serving_config_split_k_resolution():
     base = dict(page_size=4, n_pages=32)
     assert _SC(**base, max_pages_per_request=8).resolve_split_k() == 4
     assert _SC(**base, max_pages_per_request=5).resolve_split_k() == 1
-    assert _SC(**base, max_pages_per_request=12).resolve_split_k() == 6
+    assert _SC(**base, max_pages_per_request=12).resolve_split_k() == 4
+    assert _SC(page_size=16, n_pages=2560,
+               max_pages_per_request=160).resolve_split_k() == 4
+    assert _SC(**base, max_pages_per_request=9).resolve_split_k() == 3
     assert _SC(**base, max_pages_per_request=8, split_k=1).resolve_split_k() == 1
     assert _SC(**base, max_pages_per_request=8, split_k=3).resolve_split_k() == 2
     assert _SC(**base, max_pages_per_request=8, split_k=16).resolve_split_k() == 8

@@ -22,11 +22,16 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro.serving.config import ServingConfig
+
 # qwen2-1.5b attention widths and the chip_smoke.py pool geometry
 H, KH, DH, PG = 12, 2, 128, 16
 B, M, N_ROWS, LAYERS = 8, 64, 1024, 28
 CHUNK = 256
-SPLITS = 32           # ServingConfig.resolve_split_k() at M = 64
+# the engine's split count for this table width (its auto rule)
+SPLITS = ServingConfig(
+    page_size=PG, n_pages=N_ROWS - 1, max_batch=B, max_pages_per_request=M,
+).resolve_split_k()
 BF16, I32 = jnp.bfloat16, jnp.int32
 
 
