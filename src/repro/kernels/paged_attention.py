@@ -74,10 +74,12 @@ NAN_K, INF_K, EV_K, NAN_V, INF_V, EV_V, EV_TOTAL = range(7)
 # for that operand), so the default cannot be None.
 DEFAULT_DETECTOR = "default"
 
-# scoped VMEM of the chunked-q prefill kernel: its (C*H, pg) score tile
-# spills registers (~11 MB at C = 256, H = 12), which overflows the default
-# scoped limit once the kernel runs under shard_map
+# scoped VMEM of the chunked-q prefill kernel, at least: its (C*H, pg) score
+# tile spills registers (~11 MB at C = 256, H = 12), which overflows the
+# default scoped limit once the kernel runs under shard_map.  A wider chunk
+# (C*H rows) asks for more, up to _PREFILL_VMEM_MAX (a v5e core has 128 MiB)
 _PREFILL_VMEM_BYTES = 32 << 20
+_PREFILL_VMEM_MAX = 100 << 20
 
 # per-slot chunk-start sentinel for the sharded prefill walk: a slot whose
 # q_start carries this value belongs to another device's shard — every
@@ -481,6 +483,22 @@ def _paged_prefill_kernel(
         lo_ref[0, 0] = l_ref[:, 0]
 
 
+def _prefill_vmem_bytes(C: int, H: int, Dh: int, q_itemsize: int) -> int:
+    """Scoped VMEM for one grid cell of the chunked prefill: twice the bytes
+    its blocks and scratch hold (the compiler's score-tile spills and
+    temporaries come on top, ~35% at 48 heads), never below
+    ``_PREFILL_VMEM_BYTES`` so a chunk that fits there compiles as before,
+    and never above ``_PREFILL_VMEM_MAX``, where the compiler reports the
+    overflow.  Every term scales with the cell's ``C * H`` query rows."""
+    rows = C * H
+    scratch = rows * (Dh + 2 * 128) * 4              # acc, m and l tiles
+    q_in = 2 * rows * Dh * q_itemsize                # double-buffered blocks
+    acc_out = 2 * rows * Dh * 4
+    ml_out = 2 * 2 * rows * 4                        # m and l rows
+    need = 2 * (scratch + q_in + acc_out + ml_out)
+    return min(max(_PREFILL_VMEM_BYTES, need), _PREFILL_VMEM_MAX)
+
+
 def _prefill_partials(
     q, k_pages, v_pages, block_tables, qs_slot, layer,
     *, consts, policy_k, constant_k, policy_v, constant_v, interpret,
@@ -555,7 +573,9 @@ def _prefill_partials(
             jax.ShapeDtypeStruct((8,), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_PREFILL_VMEM_BYTES
+            vmem_limit_bytes=_prefill_vmem_bytes(
+                C, H, Dh, jnp.dtype(q.dtype).itemsize
+            )
         ),
         interpret=interpret,
     )(

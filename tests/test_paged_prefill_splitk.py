@@ -71,6 +71,49 @@ def test_prefill_kernel_matches_oracle_with_poisoned_pages(policy, constant):
     assert int(counts[pa.EV_TOTAL]) == 2
 
 
+def test_prefill_kernel_at_starcoder2_widths_repairs_and_counts_once():
+    """starcoder2-15b's attention widths at the engine's chunk: 48 query /
+    4 KV heads (group 12), head_dim 128, 256-row chunks over 16-token bf16
+    pages — 12,288 (C, H) rows a grid cell, the chunk that overflowed the
+    32 MiB scoped VMEM.  Ragged placement (one request resumes mid-page,
+    one starts at 0, their pages interleaved), a NaN in a K page and an
+    Inf in a V page inside the causal horizon: the output matches the
+    oracle, and each poisoned page is repaired and counted exactly once."""
+    H, Kh, Dh, pg, C, M = 48, 4, 128, 16, 256, 20
+    key = jax.random.PRNGKey(31)
+    k_pages, v_pages = _pool(key, P=2 * M + 1, L=2, pg=pg, Kh=Kh, Dh=Dh)
+    bt = jnp.asarray(np.arange(2 * M).reshape(M, 2).T, jnp.int32)
+    q_start = jnp.asarray([37, 0], jnp.int32)
+    k_pages = k_pages.at[int(bt[0, 3]), 1, 5, 2, 40].set(jnp.nan)
+    v_pages = v_pages.at[int(bt[1, 9]), 1, 11, 3, 7].set(jnp.inf)
+    k_pages = k_pages.at[2 * M, 1, 0, 0, 0].set(jnp.nan)   # in no table
+    k_pages, v_pages = k_pages.astype(jnp.bfloat16), v_pages.astype(jnp.bfloat16)
+    q = jax.random.normal(
+        jax.random.fold_in(key, 1), (2, C, H, Dh), jnp.float32
+    ).astype(jnp.bfloat16)
+    # above the 32 MiB the qwen2 chunk (3,072 rows) compiles under
+    assert pa._prefill_vmem_bytes(C, H, Dh, 2) > pa._PREFILL_VMEM_BYTES
+    assert pa._prefill_vmem_bytes(C, 12, Dh, 2) == pa._PREFILL_VMEM_BYTES
+
+    out, slot, counts = pa.paged_prefill_raw(
+        q, k_pages, v_pages, bt, q_start, jnp.int32(1), policy="zero",
+    )
+    ref_out, ref_slot = ref.paged_prefill_ref(
+        q, k_pages, v_pages, bt, q_start, layer=1, policy="zero",
+    )
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref_out, np.float32),
+        atol=2e-2, rtol=2e-2,
+    )
+    np.testing.assert_array_equal(np.asarray(slot), np.asarray(ref_slot))
+    expect = np.zeros((2, M), np.int32)
+    expect[0, 3] = expect[1, 9] = 1
+    np.testing.assert_array_equal(np.asarray(slot), expect)
+    assert int(counts[pa.NAN_K]) == 1 and int(counts[pa.INF_V]) == 1
+    assert int(counts[pa.EV_TOTAL]) == 2
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+
+
 def test_prefill_kernel_causal_mask_matches_decode_walk():
     """Row c of a chunk must see exactly the prefix a decode at position
     ``q_start + c`` sees: run the decode kernel once per chunk row and

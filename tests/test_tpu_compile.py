@@ -5,7 +5,10 @@ and memory-space rules.  Here each Pallas kernel of the serving path is
 compiled with ``interpret=False`` for a described, not attached, v5e chip
 at qwen2-1.5b attention widths (bf16, 12 query / 2 KV heads, head_dim
 128, 16-token pages) and the serving geometry ``chip_smoke.py`` runs, and
-the compiled HLO must hold the kernel (``tpu_custom_call``).  The
+the compiled HLO must hold the kernel (``tpu_custom_call``).  The chunked
+prefill and split-K decode also compile at starcoder2-15b's widths (48 /
+4 heads, a 10-layer pool) and the geometry of its benchmark cell: a
+256-row chunk there holds 12,288 (C, H) rows in one grid cell.  The
 four-chip cases compile the shard_map wrappers over a 4x1 mesh of the
 described devices.
 
@@ -28,11 +31,23 @@ from repro.serving.config import ServingConfig
 H, KH, DH, PG = 12, 2, 128, 16
 B, M, N_ROWS, LAYERS = 8, 64, 1024, 28
 CHUNK = 256
-# the engine's split count for this table width (its auto rule)
-SPLITS = ServingConfig(
-    page_size=PG, n_pages=N_ROWS - 1, max_batch=B, max_pages_per_request=M,
-).resolve_split_k()
 BF16, I32 = jnp.bfloat16, jnp.int32
+
+
+def _splits(n_rows, b, m):
+    """The engine's split count for this table width (its auto rule)."""
+    return ServingConfig(
+        page_size=PG, n_pages=n_rows - 1, max_batch=b, max_pages_per_request=m,
+    ).resolve_split_k()
+
+
+SPLITS = _splits(N_ROWS, B, M)
+# (query heads, KV heads, pool rows, layers, batch, table width) per model;
+# starcoder2-15b.stage4 as its benchmark cell serves it
+WIDTHS = {
+    "qwen2": (H, KH, N_ROWS, LAYERS, B, M),
+    "starcoder2": (48, 4, 2049, 10, 8, 256),
+}
 
 
 @pytest.fixture(scope="module")
@@ -73,11 +88,13 @@ def _pa():
     return importlib.import_module("repro.kernels.paged_attention")
 
 
-def _single_chip_case(name, one_chip):
+def _single_chip_case(name, one_chip, widths="qwen2"):
     """(jitted fn, abstract args) of one single-chip kernel call."""
     pa = _pa()
     scrub = importlib.import_module("repro.kernels.scrub")
     mm = importlib.import_module("repro.kernels.repair_matmul")
+    H, KH, N_ROWS, LAYERS, B, M = WIDTHS[widths]
+    SPLITS = _splits(N_ROWS, B, M)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -128,14 +145,18 @@ def _single_chip_case(name, one_chip):
 
 
 @pytest.mark.parametrize(
-    "name",
+    "name,widths",
     [
-        "paged_decode", "paged_decode_splitk", "paged_prefill",
-        "scrub", "scrub_pages", "repair_matmul",
+        pytest.param(name, "qwen2", id=name)
+        for name in ("paged_decode", "paged_decode_splitk", "paged_prefill",
+                     "scrub", "scrub_pages", "repair_matmul")
+    ] + [
+        pytest.param(name, "starcoder2", id=f"{name}-starcoder2")
+        for name in ("paged_decode_splitk", "paged_prefill")
     ],
 )
-def test_kernel_compiles_for_v5e(name, one_chip):
-    fn, args = _single_chip_case(name, one_chip)
+def test_kernel_compiles_for_v5e(name, widths, one_chip):
+    fn, args = _single_chip_case(name, one_chip, widths)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # the kernels stream HBM blocks: no whole-pool temporaries
