@@ -28,7 +28,8 @@ Layout:
 Two decode walks share that contract.  A block-table slot ``j`` is *live*
 when its first position ``j * pg`` lies within the request's context
 (positions ``0..pos``); the slots past it are null padding or pages
-allocated ahead.
+allocated ahead.  The chunked prefill (``paged_prefill_raw``) walks its
+live pages the split-K way, up to the chunk's last row.
 
 * Split-K (``paged_attention_splitk_raw``, the serving path once the table
   is 8 pages wide): grid (B, splits); each cell copies only its live
@@ -74,21 +75,26 @@ NAN_K, INF_K, EV_K, NAN_V, INF_V, EV_V, EV_TOTAL = range(7)
 # for that operand), so the default cannot be None.
 DEFAULT_DETECTOR = "default"
 
-# scoped VMEM of the chunked-q prefill kernel, at least: its (C*H, pg) score
-# tile spills registers (~11 MB at C = 256, H = 12), which overflows the
-# default scoped limit once the kernel runs under shard_map.  A wider chunk
-# (C*H rows) asks for more, up to _PREFILL_VMEM_MAX (a v5e core has 128 MiB)
+# scoped VMEM of the chunked-q prefill kernel, at least: its score tiles
+# overflow the default scoped limit once the kernel runs under shard_map.  A
+# grid cell with more query rows asks for more
 _PREFILL_VMEM_BYTES = 32 << 20
-_PREFILL_VMEM_MAX = 100 << 20
+
+# query rows (chunk rows x query heads) of one prefill grid cell, at most: a
+# 256-row chunk at 12 heads fits whole; at 48 heads it takes four row tiles.
+# A tile's rows per KV head fill whole 128-lane rows, so a head group whose
+# 128-lane unit alone holds more rows (32 heads, no sharing) takes that unit
+_PREFILL_TILE_ROWS = 3072
 
 # per-slot chunk-start sentinel for the sharded prefill walk: a slot whose
-# q_start carries this value belongs to another device's shard — every
-# causal comparison fails (tq is hugely negative) and the count gate is off
+# q_start carries this value belongs to another device's shard — no chunk
+# row reaches it, so it is never read nor counted
 NO_SLOT = -(1 << 30)
 
-# keys per block of the split-K walk: one lane-dense score tile of 2 x 128
-# lanes, so a 16-token page pool walks up to 16 pages per copy block
-_SPLITK_BLOCK_KEYS = 256
+# keys per block of the live page walks (split-K decode, chunked prefill):
+# one lane-dense score tile of 2 x 128 lanes, so a 16-token page pool walks
+# up to 16 pages per copy block
+_BLOCK_KEYS = 256
 
 
 def _record_counts(
@@ -114,18 +120,17 @@ def _repair_and_count(
     *, policy_k: str, constant_k: float, policy_v: str, constant_v: float,
     gate=None,
 ):
-    """Fused on-read repair of one page's K/V rows (the trap) — shared by
-    the serial decode and the prefill kernels.  Per-operand fill selection:
+    """Fused on-read repair of one page's K/V rows (the trap) in the serial
+    decode kernel.  Per-operand fill selection:
     each tile repairs with ITS operand's rule fill (row 0 = K, row 1 = V),
     so a mixed-fill RuleSet compiles into one kernel instead of forcing the
     gathered fallback.  Accumulates the AT_* event counts and writes the
     per-page-slot fatal count the reactive repair manager consumes.
 
     ``gate`` (int32 0/1, default 1) masks the *counting* side only: a slot
-    past the request's context, or (under the sharded walk) one whose page
-    another device owns, is still streamed and repaired here (harmless —
-    its scores are fully masked) but reports nothing, so each page is
-    counted by exactly one device and only where a context reads it.
+    past the request's context is still streamed and repaired here
+    (harmless — its scores are fully masked) but reports nothing, so a page
+    is counted only where a context reads it.
 
     ``slot`` is the ``(b, j)`` block-table slot this grid step visits."""
     if gate is None:
@@ -391,201 +396,496 @@ def paged_attention(
 
 
 # --------------------------------------------------------------------------
+# The live page walk shared by chunked prefill and split-K decode: a grid
+# cell copies only its live slots' pages from the HBM pool leaves, several
+# pages per double-buffered block, reads each block as dense 32-bit words
+# and repairs it in VMEM.
+# --------------------------------------------------------------------------
+def _block_pages(ns: int, pg: int) -> int:
+    """Pages per block of a live walk over ``ns`` block-table slots: the
+    largest divisor of ``ns`` whose keys fill at most one lane-dense score
+    tile of ``_BLOCK_KEYS``."""
+    fit = [d for d in range(1, ns + 1)
+           if ns % d == 0 and d * pg <= _BLOCK_KEYS]
+    return max(fit, default=1)
+
+
+def _live_blocks(live, bp: int):
+    """Trip counts of a live walk: ``live`` (..., ns) bool per slot gives
+    (...) int32, the number of ``bp``-slot blocks up to and including the
+    last block that holds a live slot."""
+    *lead, ns = live.shape
+    nb = ns // bp
+    idx = jnp.arange(1, nb + 1, dtype=jnp.int32)
+    any_live = live.reshape(*lead, nb, bp).any(-1)
+    return jnp.max(jnp.where(any_live, idx, 0), axis=-1).astype(jnp.int32)
+
+
+def _fill_bits(policy: str, constant: float, dtype) -> int:
+    """The bit pattern a value-independent fill writes into a repaired lane
+    of ``dtype`` — the live walk repairs whole blocks of raw words."""
+    value = {
+        "zero": 0.0,
+        "constant": constant,
+        "clamp_finite_max": float(jnp.finfo(dtype).max),
+    }.get(policy)
+    if value is None:
+        raise ValueError(
+            f"the live page walk fills with zero, constant or "
+            f"clamp_finite_max, not {policy!r}"
+        )
+    bits = np.asarray(value, jnp.dtype(dtype))
+    return int(bits.view(np.uint16 if bits.itemsize == 2 else np.uint32))
+
+
+def _check_walk_pool(k_pages, v_pages):
+    assert v_pages.shape == k_pages.shape, (k_pages.shape, v_pages.shape)
+    assert k_pages.dtype in (jnp.float32, jnp.bfloat16), (
+        f"the live page walk reads float32 or bfloat16 pools, "
+        f"not {k_pages.dtype}"
+    )
+    Kh = k_pages.shape[3]
+    assert Kh % (4 // k_pages.dtype.itemsize) == 0, (
+        f"a 16-bit pool packs KV heads in pairs: Kh={Kh} must be even"
+    )
+
+
+def _repair_heads(w, consts, fill: int, width: int):
+    """The trap on a block of raw 32-bit words ``w``: each ``width``-bit
+    lane is detected and, where fatal, replaced by the ``fill`` bits.  A
+    16-bit pool packs two KV heads per word (the lower head in the low
+    half); each half is moved to the high half of a word, where its
+    bfloat16 bits are the float32 of the same value, and detected there
+    with the detector constants shifted alike.  Returns, per head in the
+    words, ``(value f32, NaN-bucket mask, non-NaN-bucket mask)``
+    (``common.masks_from_consts``)."""
+    if width == 32:
+        halves, shift = [w], 0
+    else:
+        halves, shift = [w << 16, w & jnp.uint32(0xFFFF0000)], 16
+    # masks, range threshold and bit pattern move with the lane; the
+    # flags (2) and the row bound (6, unused here) do not
+    consts = tuple(
+        c << shift if k in (0, 1, 3, 4, 5) else c for k, c in enumerate(consts)
+    )
+    out = []
+    for x in halves:
+        nan_m, inf_m = common.masks_from_consts(x, consts)
+        fixed = jnp.where(nan_m | inf_m, jnp.uint32(fill << shift), x)
+        out.append(
+            (jax.lax.bitcast_convert_type(fixed, jnp.float32), nan_m, inf_m)
+        )
+    return out
+
+
+def _block_heads(ref, buf, consts, fill: int, n_keys: int):
+    """One operand's block of ``n_keys`` keys in buffer ``buf``, repaired
+    in VMEM: ``(value f32 (n_keys, Dh), nan, inf)`` per KV head.  The block
+    is read as dense 32-bit words — a 16-bit pool's (Kh, Dh) page rows pair
+    heads 2r, 2r+1 in one word — so each load fills whole vregs where
+    (Kh, Dh) tiles would pad to the sublane count."""
+    n_kv, Dh = ref.shape[-2:]
+    width = jnp.dtype(ref.dtype).itemsize * 8                # 16 or 32
+    stride = n_kv // (32 // width)       # word rows per key position
+    wref = ref.at[buf].bitcast(jnp.uint32).reshape(n_keys * stride, Dh)
+    out = []
+    for r in range(stride):
+        w = wref[pl.ds(r, n_keys, stride=stride)] if stride > 1 else wref[...]
+        out += _repair_heads(w, consts, fill, width)
+    return out
+
+
+def _zero_slots(slot_ref, b, base: int | jax.Array, ns: int):
+    """Zero slot counts ``(b, base .. base + ns)``: slots a walk never
+    reads report 0, read pages overwrite theirs (a few scalar stores per
+    loop step)."""
+    u = max(d for d in range(1, 9) if ns % d == 0)
+
+    def zero(t, carry):
+        for k in range(u):
+            slot_ref[b, base + t * u + k] = jnp.int32(0)
+        return carry
+
+    jax.lax.fori_loop(0, ns // u, zero, 0)
+
+
+def _count_block(slot_ref, counts_ref, k_heads, v_heads, *, b, j0, pg, live):
+    """A block's per-page fatal counts at their exact ``(b, slot)``, on the
+    rare path where a lane fired anywhere in it; ``live(j)`` gates each
+    page's count."""
+    fatal = functools.reduce(
+        jnp.logical_or, [n | f for _, n, f in k_heads + v_heads]
+    )
+    n_keys = fatal.shape[0]
+
+    @pl.when(jnp.sum(fatal.astype(jnp.int32)) > 0)
+    def _account():
+        def page(hs, p, kind):
+            return sum(
+                jnp.sum(h[kind][p * pg:(p + 1) * pg].astype(jnp.int32))
+                for h in hs
+            )
+
+        for p in range(n_keys // pg):
+            _record_counts(
+                slot_ref, counts_ref, (b, j0 + p),
+                page(k_heads, p, 1), page(k_heads, p, 2),
+                page(v_heads, p, 1), page(v_heads, p, 2),
+                live(j0 + p).astype(jnp.int32),
+            )
+
+
+def _walk_live_blocks(
+    n_blocks, live, attend, *, bt_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
+    b, base, lay, bp: int,
+):
+    """Walk ``n_blocks`` blocks of ``bp`` block-table slots from ``base``:
+    each block's live pages (``live(j)``) are copied from the HBM pool
+    leaves into one half of the double-buffered ``k_buf``/``v_buf`` while
+    ``attend(i, buf)`` reads the other.  A dead slot is not fetched: its
+    buffer page is zeroed instead, so stale bits never meet the value
+    contraction."""
+    from jax.experimental.pallas import tpu as pltpu  # local: CPU-safe import
+
+    def copies(i, buf, p):
+        page = bt_ref[b, base + i * bp + p]
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[page, lay], k_buf.at[buf, p], sems.at[0, buf]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[page, lay], v_buf.at[buf, p], sems.at[1, buf]
+            ),
+        )
+
+    def each_copy(i, buf, op, dead=None):
+        """``op`` ("start" or "wait") on the K and V copies of each live
+        page of block i — one branch when the whole block is live, else
+        one per slot, with ``dead(p)`` on the rest."""
+        slots = [base + i * bp + p for p in range(bp)]
+        full = functools.reduce(jnp.logical_and, [live(j) for j in slots])
+
+        def act(p):
+            for cp in copies(i, buf, p):
+                getattr(cp, op)()
+
+        @pl.when(full)
+        def _all():
+            for p in range(bp):
+                act(p)
+
+        @pl.when(jnp.logical_not(full))
+        def _some():
+            for p, j in enumerate(slots):
+                pl.when(live(j))(functools.partial(act, p))
+                if dead is not None:
+                    pl.when(jnp.logical_not(live(j)))(
+                        functools.partial(dead, p)
+                    )
+
+    def start(i, buf):
+        def clear(p):
+            k_buf[buf, p] = jnp.zeros(k_buf.shape[2:], k_buf.dtype)
+            v_buf[buf, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+
+        each_copy(i, buf, "start", clear)
+
+    @pl.when(n_blocks > 0)
+    def _prime():
+        start(0, 0)
+
+    def body(i, carry):
+        buf = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blocks)
+        def _prefetch():
+            start(i + 1, 1 - buf)
+
+        each_copy(i, buf, "wait")
+        attend(i, buf)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, body, 0)
+
+
+def _walk_scratch(bp: int, k_pages, v_pages):
+    """The double-buffered block copies and their semaphores."""
+    from jax.experimental.pallas import tpu as pltpu  # local: CPU-safe import
+
+    _, _, pg, Kh, Dh = k_pages.shape
+    return [
+        pltpu.VMEM((2, bp, pg, Kh, Dh), k_pages.dtype),
+        pltpu.VMEM((2, bp, pg, Kh, Dh), v_pages.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),     # (K|V, buffer)
+    ]
+
+
+# --------------------------------------------------------------------------
 # Chunked-q paged prefill: admission attends straight off the pool too.
 # --------------------------------------------------------------------------
 def _paged_prefill_kernel(
-    consts_ref,      # int32[2, 8]  detector constants: row 0 K, row 1 V
-    bt_ref,          # int32[B, M]  block tables (also drives the index maps)
-    qstart_ref,      # int32[B, M]  chunk-row-0 position, per block slot
-    layer_ref,       # int32[1]     which L row of the pool leaves
-    q_ref, k_ref, v_ref,
+    consts_ref,      # int32[2, 8]      detector constants: row 0 K, row 1 V
+    bt_ref,          # int32[B, M]      block tables (page ids of the copies)
+    qstart_ref,      # int32[B, M]      chunk-row-0 position, per block slot
+    nblk_ref,        # int32[B, T]      live blocks to walk, per row tile
+    layer_ref,       # int32[1]         which L row of the pool leaves
+    q_ref, k_hbm, v_hbm,
     o_ref, mo_ref, lo_ref, slot_ref, counts_ref,
-    acc_ref, m_ref, l_ref,
-    *, sm_scale: float,
-    policy_k: str, constant_k: float, policy_v: str, constant_v: float,
-    pg: int, n_kv: int, group: int, nm: int, nc: int,
+    k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
+    *, sm_scale: float, fill_k: int, fill_v: int,
+    pg: int, n_kv: int, group: int, nc: int, ct: int, bp: int,
 ):
-    b, j = pl.program_id(0), pl.program_id(1)
-    step = b * pl.num_programs(1) + j
+    b, t = pl.program_id(0), pl.program_id(1)
+    M = bt_ref.shape[1]
+    R = ct * group                       # query rows per KV head in a tile
+    N = bp * pg
+    # the tile's last real row, from q_start: rows padded past the chunk
+    # (the last tile's, when C is not a whole number of tiles) widen no walk
+    horizon = jnp.minimum((t + 1) * ct, nc) - 1
+    # the last tile's horizon is the chunk's last row: it reads every page
+    # any tile reads, so it alone counts them — each page counted once
+    counting = t == pl.num_programs(1) - 1
 
-    @pl.when(step == 0)
+    @pl.when((b == 0) & (t == 0))
     def _init_counts():
         common.zero_counts(counts_ref, 8)
 
-    @pl.when(j == 0)
-    def _init_state():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    @pl.when(counting)
+    def _init_slots():
+        _zero_slots(slot_ref, b, 0, M)
 
-    # per-SLOT q_start: on a single device every slot carries the request's
-    # chunk start; under the sharded walk non-owned slots carry NO_SLOT,
-    # which kills every causal comparison below and gates the counts off
-    qs = qstart_ref[b, j]
-    k_fixed, v_fixed = _repair_and_count(
-        consts_ref, k_ref, v_ref, slot_ref, counts_ref, (b, j),
-        policy_k=policy_k, constant_k=constant_k,
-        policy_v=policy_v, constant_v=constant_v,
-        gate=(qs >= 0).astype(jnp.int32),
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def live(j):
+        # per-SLOT chunk start: on a single device every slot of request b
+        # carries q_start[b]; under the sharded walk non-owned slots carry
+        # NO_SLOT, which no row reaches
+        return j * pg <= qstart_ref[b, j] + horizon
+
+    rows = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
+
+    def attend(i, buf):
+        j0 = i * bp
+        k_heads = _block_heads(
+            k_buf, buf, common.consts_row(consts_ref, 0), fill_k, N
+        )
+        v_heads = _block_heads(
+            v_buf, buf, common.consts_row(consts_ref, 1), fill_v, N
+        )
+        pl.when(counting)(functools.partial(
+            _count_block, slot_ref, counts_ref, k_heads, v_heads,
+            b=b, j0=j0, pg=pg, live=live,
+        ))
+
+        # causal mask: tile row r (ordered chunk row, then query head of
+        # the group) holds chunk row t * ct + r // group and reads a key
+        # whose position less its slot's q_start is at most that row, i.e.
+        # when r >= (key - q_start - t * ct) * group.  Keys past the chunk
+        # (NO_SLOT slots among them) clamp to row C, which only the rows
+        # padded past the chunk reach.
+        qs = jnp.full((1, N), qstart_ref[b, j0], jnp.int32)
+        for p in range(1, bp):
+            qs = jnp.where(lane >= p * pg, qstart_ref[b, j0 + p], qs)
+        rel = jnp.minimum(j0 * pg + lane - qs, nc)
+        keep = rows >= (rel - t * ct) * group                # (R, N)
+
+        for h in range(n_kv):
+            # the wrapper hands q over in the contraction dtype: a bf16
+            # pool and query contract in bf16 with f32 accumulation (bf16
+            # products are exact in f32), anything else in f32
+            s = jax.lax.dot_general(
+                q_ref[0, h], k_heads[h][0].astype(q_ref.dtype),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale                                     # (R, N)
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_ref[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # empty-walk guard: a row with no readable key yet (the sharded
+            # walk's non-owned slots) keeps (m, l, acc) = (-inf, 0, 0)
+            # exactly — against a max of -inf, exp(s - m) would be 1 per
+            # masked lane
+            p_ = jnp.exp(s - jnp.where(m_new > NEG_INF * 0.5, m_new, 0.0))
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_ref[h][:, :1] * alpha + jnp.sum(p_, -1, keepdims=True)
+            # softmax weights quantize to the cache dtype before the value
+            # contraction, as in the decode walks and the gathered path
+            pv = jax.lax.dot_general(
+                p_.astype(v_buf.dtype), v_heads[h][0].astype(v_buf.dtype),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                # (R, Dh)
+            acc_ref[h] = acc_ref[h] * alpha + pv
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    _walk_live_blocks(
+        nblk_ref[b, t], live, attend,
+        bt_ref=bt_ref, k_hbm=k_hbm, v_hbm=v_hbm, k_buf=k_buf, v_buf=v_buf,
+        sems=sems, b=b, base=0, lay=layer_ref[0], bp=bp,
     )
 
-    # ---- online softmax: the whole q chunk against this page ----
-    Dh = q_ref.shape[-1]
-    R = nc * n_kv * group                                    # (C, H) rows
-    q = q_ref[0].astype(jnp.float32).reshape(nc, n_kv, group, Dh)
-    qh = jnp.moveaxis(q, 1, 0).reshape(n_kv, nc * group, Dh)
-    kb = jnp.moveaxis(k_fixed.astype(jnp.float32), 1, 0)     # (Kh, pg, Dh)
-    s = jax.lax.dot_general(
-        qh, kb, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * sm_scale                                             # (Kh, C*G, pg)
-    s = s.reshape(n_kv, nc, group, pg)
-    # causal mask, per chunk row: row c sits at context position
-    # q_start + c and may read keys at positions <= that
-    tq = qs + jax.lax.broadcasted_iota(
-        jnp.int32, (1, nc, 1, 1), 1
-    )
-    tk = j * pg + jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, pg), 3)
-    s = jnp.where(tk <= tq, s, NEG_INF)
-    # scratch rows ordered (C, Kh, G) so the flush is a plain reshape
-    s2 = jnp.moveaxis(s, 0, 1).reshape(R, pg)
-
-    m_prev = m_ref[:, 0]                                     # (R,)
-    m_new = jnp.maximum(m_prev, jnp.max(s2, axis=-1))
-    # same empty-walk guard as split-K: a shard owning none of a request's
-    # pages keeps (m, l, acc) = (-inf, 0, 0) exactly, which the LSE merge
-    # drops.  For the serial walk this is a bit-exact no-op — slot 0 always
-    # yields a real row max, so masked lanes underflow to 0.0 either way.
-    p = jnp.where(
-        s2 > NEG_INF * 0.5, jnp.exp(s2 - m_new[:, None]), 0.0
-    )                                                        # (R, pg)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-    # quantize the softmax weights to the cache dtype before the value
-    # contraction, matching the decode kernel and the gathered path
-    pk = jnp.moveaxis(p.reshape(nc, n_kv, group, pg), 1, 0)
-    pk = pk.reshape(n_kv, nc * group, pg).astype(v_fixed.dtype)
-    vb = jnp.moveaxis(v_fixed, 1, 0)                         # (Kh, pg, Dh)
-    pv = jax.lax.dot_general(
-        pk, vb, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                                        # (Kh, C*G, Dh)
-    pv = jnp.moveaxis(pv.reshape(n_kv, nc, group, Dh), 0, 1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv.reshape(acc_ref.shape)
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
-
-    @pl.when(j == nm - 1)
-    def _flush():
-        # raw partials — normalization happens in the caller / LSE merge
-        o_ref[0] = acc_ref[...].reshape(nc, n_kv * group, Dh)
-        mo_ref[0, 0] = m_ref[:, 0]
-        lo_ref[0, 0] = l_ref[:, 0]
+    # raw partials — normalization happens in the caller / LSE merge
+    o_ref[0] = acc_ref[...]
+    for h in range(n_kv):
+        mo_ref[0, h, 0] = m_ref[h, :, 0]
+        lo_ref[0, h, 0] = l_ref[h, :, 0]
 
 
-def _prefill_vmem_bytes(C: int, H: int, Dh: int, q_itemsize: int) -> int:
-    """Scoped VMEM for one grid cell of the chunked prefill: twice the bytes
-    its blocks and scratch hold (the compiler's score-tile spills and
-    temporaries come on top, ~35% at 48 heads), never below
-    ``_PREFILL_VMEM_BYTES`` so a chunk that fits there compiles as before,
-    and never above ``_PREFILL_VMEM_MAX``, where the compiler reports the
-    overflow.  Every term scales with the cell's ``C * H`` query rows."""
-    rows = C * H
+def _prefill_row_tile(C: int, H: int, group: int) -> int:
+    """Chunk rows per grid cell of the prefill walk: the whole chunk when
+    its ``C * H`` query rows fit in ``_PREFILL_TILE_ROWS``, else the most
+    chunk rows that do whose rows per KV head fill whole 128-lane rows (the
+    m and l outputs are blocked by them), at least one such unit.  The
+    chunk is padded to whole tiles."""
+    if C * H <= _PREFILL_TILE_ROWS:
+        return C
+    unit = 128 // math.gcd(group, 128)
+    return unit * max(1, _PREFILL_TILE_ROWS // (unit * H))
+
+
+def _prefill_vmem_bytes(rows: int, Dh: int, q_itemsize: int) -> int:
+    """Scoped VMEM for one grid cell of the chunked prefill, which holds
+    ``rows`` query rows (a row tile's chunk rows x query heads): twice the
+    bytes its blocks and scratch hold (the compiler's score tiles and
+    temporaries come on top), never below ``_PREFILL_VMEM_BYTES`` so a
+    tile that fits there compiles as before."""
     scratch = rows * (Dh + 2 * 128) * 4              # acc, m and l tiles
     q_in = 2 * rows * Dh * q_itemsize                # double-buffered blocks
     acc_out = 2 * rows * Dh * 4
     ml_out = 2 * 2 * rows * 4                        # m and l rows
     need = 2 * (scratch + q_in + acc_out + ml_out)
-    return min(max(_PREFILL_VMEM_BYTES, need), _PREFILL_VMEM_MAX)
+    return max(_PREFILL_VMEM_BYTES, need)
+
+
+def _prefill_live_blocks(qs_slot, pg: int, C: int, ct: int, nt: int, bp: int):
+    """(B, nt) int32 trip counts of the prefill walk: per row tile, the
+    blocks up to its last live one.  Slot ``j`` is live for tile ``t`` when
+    its first position ``j * pg`` lies at or before the tile's last real
+    row, ``q_start + min((t + 1) * ct, C) - 1`` (``qs_slot``; ``NO_SLOT``
+    on slots another device owns)."""
+    M = qs_slot.shape[1]
+    first = jnp.arange(M, dtype=jnp.int32) * pg
+    horizon = jnp.minimum(jnp.arange(1, nt + 1, dtype=jnp.int32) * ct, C) - 1
+    live = first[None, None, :] <= (
+        qs_slot[:, None, :] + horizon[None, :, None]
+    )
+    return _live_blocks(live, bp)
 
 
 def _prefill_partials(
     q, k_pages, v_pages, block_tables, qs_slot, layer,
     *, consts, policy_k, constant_k, policy_v, constant_v, interpret,
 ):
-    """Unnormalized chunked-q prefill partials over the block-table walk.
+    """Unnormalized chunked-q prefill partials over the live block-table
+    walk.
 
     ``qs_slot`` is (B, M) int32 — the chunk-row-0 context position carried
     *per block slot*.  On a single device every slot of request ``b`` holds
     the same value; under the sharded walk non-owned slots hold ``NO_SLOT``
-    (fully masked, counts gated).  Returns ``(acc (B, C, H, Dh) f32,
-    m (B, C*H) f32, l (B, C*H) f32, slot_counts, counts)``.
+    and are never read.  The grid is ``(B, row tiles)``: a cell holds
+    ``ct`` chunk rows of every query head (``_prefill_row_tile``; the chunk
+    is padded to whole tiles, the padded rows dropped) and copies
+    the pages of the slots up to its last row from HBM, in blocks of ``bp``
+    (``_block_pages``), double-buffered.  Every tile repairs the pages it
+    reads; the last tile, which reads every live page, counts them.  Slots
+    past the chunk's last row are neither fetched nor detected and read 0
+    in ``slot_counts``.  Returns ``(acc (B, C, H, Dh) f32, m (B, C*H) f32,
+    l (B, C*H) f32, slot_counts, counts)``.
     """
     B, C, H, Dh = q.shape
     P, L, pg, Kh, _ = k_pages.shape
-    assert v_pages.shape == k_pages.shape, (k_pages.shape, v_pages.shape)
+    _check_walk_pool(k_pages, v_pages)
     assert H % Kh == 0, (H, Kh)
     group = H // Kh
     M = block_tables.shape[1]
-    sm_scale = 1.0 / math.sqrt(Dh)
+    bp = _block_pages(M, pg)
+    ct = _prefill_row_tile(C, H, group)
+    nt = -(-C // ct)
+    Cp = nt * ct                         # chunk rows padded to whole tiles
+    R = ct * group
+    qs_slot = jnp.asarray(qs_slot, jnp.int32)
+    n_blocks = _prefill_live_blocks(qs_slot, pg, C, ct, nt, bp)
+    # rows per KV head, ordered (chunk row, head of the group), in the
+    # contraction dtype: the relayout and cast happen once, outside the walk
+    cdt = k_pages.dtype if q.dtype == k_pages.dtype else jnp.float32
+    q = jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
+    qh = jnp.swapaxes(q.reshape(B, Cp, Kh, group, Dh), 1, 2)
+    qh = qh.reshape(B, Kh, Cp * group, Dh).astype(cdt)
 
     from jax.experimental.pallas import tpu as pltpu  # local: CPU-safe import
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # detector consts, block tables, q_start, layer
-        grid=(B, M),
+        # detector consts, block tables, q_start, live blocks, layer
+        num_scalar_prefetch=5,
+        grid=(B, nt),
         in_specs=[
-            pl.BlockSpec((1, C, H, Dh), lambda b, j, c, bt, qs, lay: (b, 0, 0, 0)),
-            pl.BlockSpec(
-                (1, 1, pg, Kh, Dh),
-                lambda b, j, c, bt, qs, lay: (bt[b, j], lay[0], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, pg, Kh, Dh),
-                lambda b, j, c, bt, qs, lay: (bt[b, j], lay[0], 0, 0, 0),
-            ),
+            pl.BlockSpec((1, Kh, R, Dh), lambda b, t, *_: (b, 0, t, 0)),
+            # the pool leaves stay in HBM: the kernel copies live pages
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, C, H, Dh), lambda b, j, c, bt, qs, lay: (b, 0, 0, 0)),
-            # (B, 1, C*H): a unit second-minor axis keeps the block's last
-            # two dims equal to the array's, as the TPU tiling requires
-            pl.BlockSpec((1, 1, C * H), lambda b, j, c, bt, qs, lay: (b, 0, 0)),
-            pl.BlockSpec((1, 1, C * H), lambda b, j, c, bt, qs, lay: (b, 0, 0)),
+            pl.BlockSpec((1, Kh, R, Dh), lambda b, t, *_: (b, 0, t, 0)),
+            # (B, Kh, 1, C*G): a unit second-minor axis keeps the block's
+            # last two dims tiled as the TPU requires
+            pl.BlockSpec((1, Kh, 1, R), lambda b, t, *_: (b, 0, 0, t)),
+            pl.BlockSpec((1, Kh, 1, R), lambda b, t, *_: (b, 0, 0, t)),
             common.smem_spec(),     # slot counts (B, M)
             common.smem_spec(),     # counts int32[8]
         ],
-        scratch_shapes=[
-            pltpu.VMEM((C * H, Dh), jnp.float32),
-            pltpu.VMEM((C * H, 128), jnp.float32),
-            pltpu.VMEM((C * H, 128), jnp.float32),
+        scratch_shapes=_walk_scratch(bp, k_pages, v_pages) + [
+            pltpu.VMEM((Kh, R, Dh), jnp.float32),
+            pltpu.VMEM((Kh, R, 128), jnp.float32),
+            pltpu.VMEM((Kh, R, 128), jnp.float32),
         ],
     )
     acc, m, l, slot_counts, counts = pl.pallas_call(
         functools.partial(
             _paged_prefill_kernel,
-            sm_scale=sm_scale,
-            policy_k=policy_k,
-            constant_k=constant_k,
-            policy_v=policy_v,
-            constant_v=constant_v,
+            sm_scale=1.0 / math.sqrt(Dh),
+            fill_k=_fill_bits(policy_k, constant_k, k_pages.dtype),
+            fill_v=_fill_bits(policy_v, constant_v, v_pages.dtype),
             pg=pg,
             n_kv=Kh,
             group=group,
-            nm=M,
             nc=C,
+            ct=ct,
+            bp=bp,
         ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, C, H, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1, C * H), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1, C * H), jnp.float32),
+            jax.ShapeDtypeStruct((B, Kh, Cp * group, Dh), jnp.float32),
+            jax.ShapeDtypeStruct((B, Kh, 1, Cp * group), jnp.float32),
+            jax.ShapeDtypeStruct((B, Kh, 1, Cp * group), jnp.float32),
             jax.ShapeDtypeStruct((B, M), jnp.int32),
             jax.ShapeDtypeStruct((8,), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_prefill_vmem_bytes(
-                C, H, Dh, jnp.dtype(q.dtype).itemsize
+                ct * H, Dh, jnp.dtype(cdt).itemsize
             )
         ),
         interpret=interpret,
     )(
         consts,
         jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(qs_slot, jnp.int32),
+        qs_slot,
+        n_blocks,
         jnp.asarray(layer, jnp.int32).reshape(1),
-        q, k_pages, v_pages,
+        qh, k_pages, v_pages,
     )
-    return acc, m[:, 0], l[:, 0], slot_counts, counts
+
+    # back to rows ordered (chunk row, query head), padding dropped
+    def rows_first(x):
+        x = x.reshape(B, Kh, Cp, group, *x.shape[3:])[:, :, :C]
+        return jnp.swapaxes(x, 1, 2).reshape(B, C * H, *x.shape[4:])
+
+    acc = rows_first(acc).reshape(B, C, H, Dh)
+    return acc, rows_first(m[:, :, 0]), rows_first(l[:, :, 0]), slot_counts, counts
 
 
 def _prefill_normalize(out_dtype, acc, l):
@@ -628,15 +928,16 @@ def paged_prefill_raw(
     """One layer of chunked-q paged prefill with fused on-read repair.
 
     The q chunk (already written into the pool by the caller) attends over
-    the request's pages via the block-table index maps — same grid walk,
-    per-operand detector constants, and per-tile fills as decode, with the
-    chunk's causal mask (`key position <= q_start + row`) instead of a
-    single decode position.  Chunk row ``c`` must sit at context position
-    ``q_start[b] + c``; rows past the real chunk length produce garbage the
-    caller discards (they read positions beyond their causal horizon, which
-    is harmless — detection counts are per *page tile* and q-independent).
-    Returns ``(out (B, C, H, Dh), slot_counts (B, M) int32, counts
-    int32[8])``.
+    the live pages of the request's block table — the split-K walk's block
+    copies, per-operand detector constants and value-independent fills,
+    with the chunk's causal mask (`key position <= q_start + row`) instead
+    of a single decode position.  Chunk row ``c`` must sit at context
+    position ``q_start[b] + c``; rows past the real chunk length produce
+    garbage the caller discards.  Slots whose first position lies past the
+    chunk's last row (``q_start + C - 1``), null padding among them, are
+    neither fetched nor detected: ``slot_counts`` is 0 there.  Every read
+    page is repaired and counted once.  Returns ``(out (B, C, H, Dh),
+    slot_counts (B, M) int32, counts int32[8])``.
     """
     if interpret is None:
         interpret = common.default_interpret()
@@ -688,71 +989,15 @@ def paged_prefill(
 # --------------------------------------------------------------------------
 # Split-K flash decoding: the live page walk, in blocks of pages.
 # --------------------------------------------------------------------------
-def _splitk_block_pages(ns: int, pg: int) -> int:
-    """Pages per block of the split-K walk: the largest divisor of the
-    split's slot count ``ns`` whose keys fill at most one lane-dense score
-    tile of ``_SPLITK_BLOCK_KEYS``."""
-    fit = [d for d in range(1, ns + 1)
-           if ns % d == 0 and d * pg <= _SPLITK_BLOCK_KEYS]
-    return max(fit, default=1)
-
-
 def _splitk_live_blocks(pos_slot, pg: int, splits: int, bp: int):
     """(B, splits) int32 trip counts of the split-K walk: per split, the
     number of ``bp``-page blocks up to and including its last live one.  A
     slot ``j`` is live when its first position ``j * pg`` lies within its
     bound (``pos_slot``; -1 on slots another device owns)."""
     B, M = pos_slot.shape
-    nb = M // splits // bp
     first = jnp.arange(M, dtype=jnp.int32) * pg
-    live = (first[None, :] <= pos_slot).reshape(B, splits, nb, bp)
-    idx = jnp.arange(1, nb + 1, dtype=jnp.int32)
-    return jnp.max(jnp.where(live.any(-1), idx, 0), axis=-1).astype(jnp.int32)
-
-
-def _fill_bits(policy: str, constant: float, dtype) -> int:
-    """The bit pattern a value-independent fill writes into a repaired lane
-    of ``dtype`` — the split-K walk repairs whole blocks of raw words."""
-    value = {
-        "zero": 0.0,
-        "constant": constant,
-        "clamp_finite_max": float(jnp.finfo(dtype).max),
-    }.get(policy)
-    if value is None:
-        raise ValueError(
-            f"the split-K walk fills with zero, constant or clamp_finite_max, "
-            f"not {policy!r}"
-        )
-    bits = np.asarray(value, jnp.dtype(dtype))
-    return int(bits.view(np.uint16 if bits.itemsize == 2 else np.uint32))
-
-
-def _repair_heads(w, consts, fill: int, width: int):
-    """The trap on a block of raw 32-bit words ``w``: each ``width``-bit
-    lane is detected and, where fatal, replaced by the ``fill`` bits.  A
-    16-bit pool packs two KV heads per word (the lower head in the low
-    half); each half is moved to the high half of a word, where its
-    bfloat16 bits are the float32 of the same value, and detected there
-    with the detector constants shifted alike.  Returns, per head in the
-    words, ``(value f32, NaN-bucket mask, non-NaN-bucket mask)``
-    (``common.masks_from_consts``)."""
-    if width == 32:
-        halves, shift = [w], 0
-    else:
-        halves, shift = [w << 16, w & jnp.uint32(0xFFFF0000)], 16
-    # masks, range threshold and bit pattern move with the lane; the
-    # flags (2) and the row bound (6, unused here) do not
-    consts = tuple(
-        c << shift if k in (0, 1, 3, 4, 5) else c for k, c in enumerate(consts)
-    )
-    out = []
-    for x in halves:
-        nan_m, inf_m = common.masks_from_consts(x, consts)
-        fixed = jnp.where(nan_m | inf_m, jnp.uint32(fill << shift), x)
-        out.append(
-            (jax.lax.bitcast_convert_type(fixed, jnp.float32), nan_m, inf_m)
-        )
-    return out
+    live = (first[None, :] <= pos_slot).reshape(B, splits, M // splits)
+    return _live_blocks(live, bp)
 
 
 def _paged_splitk_kernel(
@@ -767,12 +1012,8 @@ def _paged_splitk_kernel(
     *, sm_scale: float, fill_k: int, fill_v: int,
     pg: int, n_kv: int, group: int, ns: int, bp: int,
 ):
-    from jax.experimental.pallas import tpu as pltpu  # local: CPU-safe import
-
     b, g = pl.program_id(0), pl.program_id(1)
-    lay = layer_ref[0]
     base = g * ns                        # first block-table slot of the split
-    n_blocks = nblk_ref[b, g]
     H = n_kv * group
     N = bp * pg
     Dh = q_ref.shape[-1]
@@ -781,16 +1022,7 @@ def _paged_splitk_kernel(
     def _init_counts():
         common.zero_counts(counts_ref, 8)
 
-    # slots the walk never reads report 0; read pages overwrite theirs
-    # (a few scalar stores per loop step)
-    u = max(d for d in range(1, 9) if ns % d == 0)
-
-    def _zero_slots(t, carry):
-        for k in range(u):
-            slot_ref[b, base + t * u + k] = jnp.int32(0)
-        return carry
-
-    jax.lax.fori_loop(0, ns // u, _zero_slots, 0)
+    _zero_slots(slot_ref, b, base, ns)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -800,96 +1032,18 @@ def _paged_splitk_kernel(
         # carries pos[b]; under the sharded walk non-owned slots carry -1
         return j * pg <= pos_ref[b, j]
 
-    def copies(i, buf, p):
-        page = bt_ref[b, base + i * bp + p]
-        return (
-            pltpu.make_async_copy(
-                k_hbm.at[page, lay], k_buf.at[buf, p], sems.at[0, buf]
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[page, lay], v_buf.at[buf, p], sems.at[1, buf]
-            ),
-        )
-
-    def each_copy(i, buf, op, dead=None):
-        """``op`` ("start" or "wait") on the K and V copies of each live
-        page of block i — one branch when the whole block is live, else
-        one per slot, with ``dead(p)`` on the rest."""
-        slots = [base + i * bp + p for p in range(bp)]
-        full = functools.reduce(jnp.logical_and, [live(j) for j in slots])
-
-        def act(p):
-            for cp in copies(i, buf, p):
-                getattr(cp, op)()
-
-        @pl.when(full)
-        def _all():
-            for p in range(bp):
-                act(p)
-
-        @pl.when(jnp.logical_not(full))
-        def _some():
-            for p, j in enumerate(slots):
-                pl.when(live(j))(functools.partial(act, p))
-                if dead is not None:
-                    pl.when(jnp.logical_not(live(j)))(
-                        functools.partial(dead, p)
-                    )
-
-    def start(i, buf):
-        """Issue block i's page copies into buffer ``buf``, all at once.
-        A dead slot is not fetched: its buffer page is zeroed instead, so
-        stale bits never meet the value contraction."""
-        def clear(p):
-            k_buf[buf, p] = jnp.zeros(k_buf.shape[2:], k_buf.dtype)
-            v_buf[buf, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
-
-        each_copy(i, buf, "start", clear)
-
-    def wait(i, buf):
-        each_copy(i, buf, "wait")
-
-    width = jnp.dtype(k_buf.dtype).itemsize * 8              # 16 or 32
-    pack = 32 // width                   # KV heads per 32-bit word
-    stride = n_kv // pack                # word rows per key position
-
-    def heads(ref, buf, consts, fill):
-        """One operand's block, repaired in VMEM: ``(value, nan, inf)`` per
-        KV head.  The block is read as dense 32-bit words — a 16-bit pool's
-        (Kh, Dh) page rows pair heads 2r, 2r+1 in one word — so each load
-        fills whole vregs where (Kh, Dh) tiles would pad to the sublane
-        count."""
-        wref = ref.at[buf].bitcast(jnp.uint32).reshape(N * stride, Dh)
-        out = []
-        for r in range(stride):
-            w = wref[pl.ds(r, N, stride=stride)] if stride > 1 else wref[...]
-            out += _repair_heads(w, consts, fill, width)
-        return out
-
     def attend(i, buf):
         j0 = base + i * bp
-        k_heads = heads(k_buf, buf, common.consts_row(consts_ref, 0), fill_k)
-        v_heads = heads(v_buf, buf, common.consts_row(consts_ref, 1), fill_v)
-        fatal = functools.reduce(
-            jnp.logical_or, [n | f for _, n, f in k_heads + v_heads]
+        k_heads = _block_heads(
+            k_buf, buf, common.consts_row(consts_ref, 0), fill_k, N
         )
-
-        @pl.when(jnp.sum(fatal.astype(jnp.int32)) > 0)
-        def _account():
-            # rare path: a lane fired somewhere in the block — count per page
-            def page(hs, p, kind):
-                return sum(
-                    jnp.sum(h[kind][p * pg:(p + 1) * pg].astype(jnp.int32))
-                    for h in hs
-                )
-
-            for p in range(bp):
-                _record_counts(
-                    slot_ref, counts_ref, (b, j0 + p),
-                    page(k_heads, p, 1), page(k_heads, p, 2),
-                    page(v_heads, p, 1), page(v_heads, p, 2),
-                    live(j0 + p).astype(jnp.int32),
-                )
+        v_heads = _block_heads(
+            v_buf, buf, common.consts_row(consts_ref, 1), fill_v, N
+        )
+        _count_block(
+            slot_ref, counts_ref, k_heads, v_heads,
+            b=b, j0=j0, pg=pg, live=live,
+        )
 
         # ---- online softmax over the block's N = bp * pg keys ----
         # bf16 products are exact in f32: a bf16 pool and query contract
@@ -935,30 +1089,16 @@ def _paged_splitk_kernel(
         m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
 
-    # ---- the live blocks of this split, double-buffered ----
-    @pl.when(n_blocks > 0)
-    def _prime():
-        start(0, 0)
-
-    def body(i, carry):
-        buf = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_blocks)
-        def _prefetch():
-            start(i + 1, 1 - buf)
-
-        wait(i, buf)
-        attend(i, buf)
-        return carry
-
-    jax.lax.fori_loop(0, n_blocks, body, 0)
+    _walk_live_blocks(
+        nblk_ref[b, g], live, attend,
+        bt_ref=bt_ref, k_hbm=k_hbm, v_hbm=v_hbm, k_buf=k_buf, v_buf=v_buf,
+        sems=sems, b=b, base=base, lay=layer_ref[0], bp=bp,
+    )
 
     # raw partials — normalization happens in the LSE merge stage
     o_ref[0, 0] = acc_ref[...]
     mo_ref[0, 0, 0] = m_ref[:, 0]
     lo_ref[0, 0, 0] = l_ref[:, 0]
-
-
 def _splitk_partials(
     q, k_pages, v_pages, block_tables, pos_slot, layer,
     *, splits, consts, policy_k, constant_k, policy_v, constant_v, interpret,
@@ -970,7 +1110,7 @@ def _splitk_partials(
     ``positions[b]``; under the sharded walk non-owned slots hold ``-1``.
     A slot is read only when its first position lies within its bound:
     each grid cell ``(b, split)`` copies its live slots' pages from HBM in
-    blocks of ``bp`` (``_splitk_block_pages``), double-buffered, and walks
+    blocks of ``bp`` (``_block_pages``), double-buffered, and walks
     no further than its last live block.  Unread slots are neither fetched
     nor detected and read 0 in ``slot_counts``.  Returns ``(o_part (B,
     splits, H, Dh) f32, m_part (B, splits, H) f32, l_part (B, splits, H)
@@ -978,21 +1118,15 @@ def _splitk_partials(
     """
     B, H, Dh = q.shape
     P, L, pg, Kh, _ = k_pages.shape
-    assert v_pages.shape == k_pages.shape, (k_pages.shape, v_pages.shape)
+    _check_walk_pool(k_pages, v_pages)
     assert H % Kh == 0, (H, Kh)
     group = H // Kh
     M = block_tables.shape[1]
     assert splits >= 1 and M % splits == 0, (
         f"splits={splits} must divide the block-table width M={M}"
     )
-    assert k_pages.dtype in (jnp.float32, jnp.bfloat16), (
-        f"the split-K walk reads float32 or bfloat16 pools, not {k_pages.dtype}"
-    )
-    assert Kh % (4 // k_pages.dtype.itemsize) == 0, (
-        f"a 16-bit pool packs KV heads in pairs: Kh={Kh} must be even"
-    )
     ns = M // splits
-    bp = _splitk_block_pages(ns, pg)
+    bp = _block_pages(ns, pg)
     sm_scale = 1.0 / math.sqrt(Dh)
     pos_slot = jnp.asarray(pos_slot, jnp.int32)
     n_blocks = _splitk_live_blocks(pos_slot, pg, splits, bp)
@@ -1018,10 +1152,7 @@ def _splitk_partials(
             common.smem_spec(),     # slot counts (B, M)
             common.smem_spec(),     # counts int32[8]
         ],
-        scratch_shapes=[
-            pltpu.VMEM((2, bp, pg, Kh, Dh), k_pages.dtype),
-            pltpu.VMEM((2, bp, pg, Kh, Dh), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),     # (K|V, buffer)
+        scratch_shapes=_walk_scratch(bp, k_pages, v_pages) + [
             pltpu.VMEM((H, Dh), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
@@ -1176,10 +1307,8 @@ def paged_attention_splitk(
 
 def _owned_remap(block_tables, lo, p_local):
     """Ownership mask + shard-local row remap for one device's page range.
-    Non-owned slots are remapped to local row 0 with a sentinel bound: the
-    decode walk never fetches them, and the prefill walk streams them
-    (harmless — scores fully masked, counts gated), which keeps its grid
-    shape identical on every device."""
+    Non-owned slots are remapped to local row 0 with a sentinel bound, so
+    neither live walk fetches them and every device keeps one grid shape."""
     owned = (block_tables >= lo) & (block_tables < lo + p_local)
     return owned, jnp.where(owned, block_tables - lo, 0)
 
@@ -1365,8 +1494,8 @@ def paged_prefill_sharded(
     """Device-local chunked-q paged prefill over a page-axis-sharded pool.
 
     The sharded analogue of ``paged_prefill_raw``: non-owned block slots
-    carry the ``NO_SLOT`` q_start sentinel (every causal comparison fails,
-    counts gated), each device emits one unnormalized chunk partial, and
+    carry the ``NO_SLOT`` q_start sentinel (never read, counted 0), each
+    device emits one unnormalized chunk partial, and
     ``_shard_merge`` normalizes — bit-identical to
     ``paged_prefill_shard_ref``, and to ``paged_prefill_raw`` when one
     device owns every attended page.

@@ -166,8 +166,9 @@ def _repair_paged_rows(rows, detector, policy, constant, include_inf):
 
 
 def _live_slots(M: int, pg: int, pos):
-    """(B, M) bool: block-table slots whose first position lies within the
-    request's context — the slots a decode walk reads and counts."""
+    """(B, M) bool: block-table slots whose first position lies within
+    ``pos`` — the slots a decode walk (the context) or a prefill walk (the
+    chunk's last row) reads and counts."""
     return jnp.arange(M)[None, :] * pg <= pos[:, None]
 
 
@@ -262,6 +263,8 @@ def paged_prefill_ref(
     causal softmax — chunk row ``c`` reads key positions ``<= q_start + c``.
     Rows past the caller's real chunk length are computed like any other
     (the kernel's garbage-row contract); callers compare valid rows only.
+    Slots whose first position lies past the chunk's last row are never
+    read: they count 0 and contribute nothing, whatever their bits.
     Returns ``(out (B, C, H, Dh), slot_counts (B, M))``."""
     if k_pages.ndim == 4:
         k_pages = k_pages[:, None]
@@ -283,7 +286,11 @@ def paged_prefill_ref(
     fv, cnt_v = _repair_paged_rows(
         v_pages[bt, layer], detector_v, policy_v, constant_v, include_inf
     )
-    slot_counts = cnt_k + cnt_v
+    live = _live_slots(M, pg, qs + C - 1)                      # (B, M)
+    slot_counts = jnp.where(live, cnt_k + cnt_v, 0)
+    unread = ~live[:, :, None, None, None]
+    fk = jnp.where(unread, jnp.zeros((), fk.dtype), fk)
+    fv = jnp.where(unread, jnp.zeros((), fv.dtype), fv)
 
     T = M * pg
     fk = fk.reshape(B, T, Kh, Dh)

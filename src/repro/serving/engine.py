@@ -271,9 +271,13 @@ class Engine:
         # before any helper that syncs is first called)
         self.n_host_syncs = 0
         # decode walk coverage: the context pages of every decode batch's
-        # requests, and the B x M block-table slots those batches carried
+        # requests, and the B x M block-table slots those batches carried;
+        # prefill walk coverage: each fused chunk's pages up to its last
+        # row, and the M slots of its block table
         self.decode_pages_walked = 0
         self.decode_page_slots = 0
+        self.prefill_pages_walked = 0
+        self.prefill_page_slots = 0
         # device-local sharded hot path: engaged only when the pool's page
         # axis is genuinely sharded over exactly one mesh axis (divisible
         # row count) — otherwise the single-device kernel walk stays
@@ -827,6 +831,9 @@ class Engine:
         chunk = rest[:width]
         q_len = len(chunk)
         padded = chunk + [0] * (width - q_len)
+        M = self.cfg.max_pages_per_request
+        self.prefill_pages_walked += min(self.cfg.pages_for(start + width), M)
+        self.prefill_page_slots += M
         with TraceAnnotation("engine.prefill_chunk", rid=req.rid,
                              q_start=start, q_len=q_len):
             bt = self.pool.block_table(req.pages)[None, :]
@@ -994,6 +1001,8 @@ class Engine:
             "host_syncs_per_step": self.n_host_syncs / steps,
             "decode_pages_walked": self.decode_pages_walked,
             "decode_page_slots": self.decode_page_slots,
+            "prefill_pages_walked": self.prefill_pages_walked,
+            "prefill_page_slots": self.prefill_page_slots,
             "drain_interval": self.cfg.drain_interval,
             "sharded_kernels": self._kernel_shard is not None,
             "prefill_tokens_saved": self.prefill_tokens_saved,

@@ -74,26 +74,42 @@ def test_prefill_kernel_matches_oracle_with_poisoned_pages(policy, constant):
 def test_prefill_kernel_at_starcoder2_widths_repairs_and_counts_once():
     """starcoder2-15b's attention widths at the engine's chunk: 48 query /
     4 KV heads (group 12), head_dim 128, 256-row chunks over 16-token bf16
-    pages — 12,288 (C, H) rows a grid cell, the chunk that overflowed the
-    32 MiB scoped VMEM.  Ragged placement (one request resumes mid-page,
-    one starts at 0, their pages interleaved), a NaN in a K page and an
-    Inf in a V page inside the causal horizon: the output matches the
-    oracle, and each poisoned page is repaired and counted exactly once."""
+    pages — 12,288 (C, H) rows, walked in four row tiles of 64 chunk rows
+    (3,072 rows each, as many as qwen2's whole chunk, so both ask for the
+    same scoped VMEM).  Ragged placement (one request resumes mid-page,
+    one starts at 0, their pages interleaved); inside the chunk's causal
+    horizon a NaN in a K page every tile reads, an Inf in a V page the
+    last two tiles read, and a NaN and an Inf in pages only the last tile
+    reads: the output matches the oracle in every tile's rows, and each
+    poisoned page is repaired and counted exactly once.  A page past the
+    chunk's last row and one in no table are never read."""
     H, Kh, Dh, pg, C, M = 48, 4, 128, 16, 256, 20
     key = jax.random.PRNGKey(31)
     k_pages, v_pages = _pool(key, P=2 * M + 1, L=2, pg=pg, Kh=Kh, Dh=Dh)
     bt = jnp.asarray(np.arange(2 * M).reshape(M, 2).T, jnp.int32)
     q_start = jnp.asarray([37, 0], jnp.int32)
-    k_pages = k_pages.at[int(bt[0, 3]), 1, 5, 2, 40].set(jnp.nan)
-    v_pages = v_pages.at[int(bt[1, 9]), 1, 11, 3, 7].set(jnp.inf)
+    ct = pa._prefill_row_tile(C, H, H // Kh)
+    assert ct == 64
+    # request 0 (rows at 37..292): tile 0 reads slots 0..6, tile 3 0..18;
+    # request 1 (rows at 0..255): tile 0 reads slots 0..3, tile 2 0..11
+    k_pages = k_pages.at[int(bt[0, 3]), 1, 5, 2, 40].set(jnp.nan)   # all
+    v_pages = v_pages.at[int(bt[1, 9]), 1, 11, 3, 7].set(jnp.inf)   # 2, 3
+    k_pages = k_pages.at[int(bt[1, 14]), 1, 2, 0, 9].set(jnp.nan)   # 3
+    v_pages = v_pages.at[int(bt[0, 18]), 1, 4, 1, 3].set(-jnp.inf)  # 3
+    k_pages = k_pages.at[int(bt[0, 19]), 1, 0, 0, 0].set(jnp.nan)   # none
     k_pages = k_pages.at[2 * M, 1, 0, 0, 0].set(jnp.nan)   # in no table
     k_pages, v_pages = k_pages.astype(jnp.bfloat16), v_pages.astype(jnp.bfloat16)
     q = jax.random.normal(
         jax.random.fold_in(key, 1), (2, C, H, Dh), jnp.float32
     ).astype(jnp.bfloat16)
-    # above the 32 MiB the qwen2 chunk (3,072 rows) compiles under
-    assert pa._prefill_vmem_bytes(C, H, Dh, 2) > pa._PREFILL_VMEM_BYTES
-    assert pa._prefill_vmem_bytes(C, 12, Dh, 2) == pa._PREFILL_VMEM_BYTES
+    # a 48-head tile and the qwen2 chunk (one tile) hold 3,072 rows each
+    # and ask for the same scoped VMEM, well under the 72.4 MiB a whole
+    # 48-head chunk took in one grid cell
+    assert pa._prefill_row_tile(C, 12, 6) == C
+    assert ct * H == C * 12 == pa._PREFILL_TILE_ROWS
+    vmem = pa._prefill_vmem_bytes(ct * H, Dh, 2)
+    assert vmem == pa._prefill_vmem_bytes(C * 12, Dh, 2)
+    assert vmem == pa._PREFILL_VMEM_BYTES < (72 << 20) // 2
 
     out, slot, counts = pa.paged_prefill_raw(
         q, k_pages, v_pages, bt, q_start, jnp.int32(1), policy="zero",
@@ -107,11 +123,55 @@ def test_prefill_kernel_at_starcoder2_widths_repairs_and_counts_once():
     )
     np.testing.assert_array_equal(np.asarray(slot), np.asarray(ref_slot))
     expect = np.zeros((2, M), np.int32)
-    expect[0, 3] = expect[1, 9] = 1
+    expect[0, 3] = expect[1, 9] = expect[1, 14] = expect[0, 18] = 1
     np.testing.assert_array_equal(np.asarray(slot), expect)
-    assert int(counts[pa.NAN_K]) == 1 and int(counts[pa.INF_V]) == 1
+    assert int(counts[pa.NAN_K]) == 2 and int(counts[pa.INF_V]) == 2
+    assert int(counts[pa.EV_K]) == 2 and int(counts[pa.EV_V]) == 2
+    assert int(counts[pa.EV_TOTAL]) == 4
+    for t in range(C // ct):
+        rows = out[:, t * ct:(t + 1) * ct].astype(jnp.float32)
+        assert bool(jnp.isfinite(rows).all()), t
+
+
+def test_prefill_pads_chunk_to_whole_row_tiles():
+    """A chunk that no whole number of row tiles holds runs the same tiled
+    walk, padded: 48 query heads at C 100 take tiles of 64 rows, the second
+    padded by 28.  Every row matches the oracle, and a page only the padded
+    rows would reach is neither read nor counted — the last tile's horizon
+    is the chunk's last real row.  A head group whose 128-lane unit alone
+    holds more than 3,072 rows (32 heads, no sharing) takes that unit."""
+    H, Kh, Dh, pg, C, M = 48, 4, 16, 16, 100, 12
+    key = jax.random.PRNGKey(32)
+    k_pages, v_pages = _pool(key, P=M, L=1, pg=pg, Kh=Kh, Dh=Dh)
+    bt = jnp.arange(M, dtype=jnp.int32)[None]
+    # rows at 21..120: tile 0 (up to 84) reads slots 0..5, tile 1 0..7;
+    # the padded rows (up to 148) would reach slots 8 and 9
+    q_start = jnp.asarray([21], jnp.int32)
+    assert pa._prefill_row_tile(C, H, H // Kh) == 64
+    assert pa._prefill_row_tile(300, 32, 1) == 128
+    # that unit's 4,096 rows at 256-wide heads ask above the VMEM floor
+    assert pa._prefill_vmem_bytes(128 * 32, 256, 2) > pa._PREFILL_VMEM_BYTES
+    k_pages = k_pages.at[3, 0, 4, 1, 5].set(jnp.nan)      # both tiles
+    v_pages = v_pages.at[7, 0, 8, 2, 1].set(jnp.inf)      # tile 1
+    k_pages = k_pages.at[8, 0, 0, 0, 0].set(jnp.nan)      # padded rows only
+    q = jax.random.normal(jax.random.fold_in(key, 1), (1, C, H, Dh),
+                          jnp.float32)
+
+    out, slot, counts = pa.paged_prefill_raw(
+        q, k_pages, v_pages, bt, q_start, jnp.int32(0),
+    )
+    ref_out, ref_slot = ref.paged_prefill_ref(
+        q, k_pages, v_pages, bt, q_start, layer=0,
+    )
+    assert out.shape == (1, C, H, Dh)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref_out), atol=1e-5, rtol=1e-5
+    )
+    np.testing.assert_array_equal(np.asarray(slot), np.asarray(ref_slot))
+    expect = np.zeros((1, M), np.int32)
+    expect[0, 3] = expect[0, 7] = 1
+    np.testing.assert_array_equal(np.asarray(slot), expect)
     assert int(counts[pa.EV_TOTAL]) == 2
-    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
 
 
 def test_prefill_kernel_causal_mask_matches_decode_walk():
@@ -358,7 +418,7 @@ def test_splitk_live_walk_matches_oracle_over_ragged_contexts(splits, dtype):
     A bfloat16 pool is read as words holding two heads' lanes each."""
     key = jax.random.PRNGKey(21)
     k_pages, v_pages, bt, pos, _, _ = _live_pool(key)
-    assert pa._splitk_block_pages(_LP_M // splits, _LP_PG) > 1
+    assert pa._block_pages(_LP_M // splits, _LP_PG) > 1
     k_pages = k_pages.at[int(bt[0, 17]), 1, 3, 1, 2].set(jnp.nan)
     v_pages = v_pages.at[int(bt[3, 39]), 1, 15, 0, 7].set(jnp.inf)
     k_pages, v_pages = k_pages.astype(dtype), v_pages.astype(dtype)
@@ -423,6 +483,57 @@ def test_splitk_never_reads_past_the_context(detect):
     np.testing.assert_allclose(
         np.asarray(out)[live_rows], np.asarray(ref_out)[live_rows],
         atol=1e-5, rtol=1e-5,
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("detect", [True, False])
+def test_prefill_never_reads_past_the_chunk(detect, dtype):
+    """NaN parked in the null page and in an allocated page past the
+    chunk's last row (in the same block as the chunk's last live page):
+    neither is read, so the output stays finite even with detection off
+    (an unrepaired NaN that were read would poison the chunk through
+    0 * NaN), it matches the oracle, and both slots count 0."""
+    key = jax.random.PRNGKey(24)
+    k_pages, v_pages, bt, _, spare, null = _live_pool(key)
+    C = 8
+    # request 0's chunk ends at 332, in slot 20; request 1 fills one page
+    # of its table, null padding after; request 2's table is all null; 3
+    # ends at the table's last position
+    q_start = jnp.asarray([20 * _LP_PG + 5, 0, 0, _LP_M * _LP_PG - C],
+                          jnp.int32)
+    assert pa._block_pages(_LP_M, _LP_PG) == 10
+    bt = bt.at[0, 21].set(spare)
+    k_pages = k_pages.at[null].set(jnp.nan).at[spare].set(jnp.nan)
+    v_pages = v_pages.at[null].set(jnp.nan).at[spare].set(jnp.nan)
+    k_pages, v_pages = k_pages.astype(dtype), v_pages.astype(dtype)
+    q = jax.random.normal(
+        jax.random.fold_in(key, 1), (4, C, 4, 16), jnp.float32
+    ).astype(dtype)
+    det = "default" if detect else None
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+
+    out, slot, counts = pa.paged_prefill_raw(
+        q, k_pages, v_pages, bt, q_start, jnp.int32(0),
+        detector_k=det, detector_v=det,
+    )
+    # the all-null table (b = 2) reads the null page's slot 0
+    live_rows = [0, 1, 3]
+    assert bool(jnp.isfinite(out[jnp.asarray(live_rows)]).all())
+    slot = np.asarray(slot)
+    assert (slot[0, 21:] == 0).all()
+    assert (slot[1, 1:] == 0).all() and (slot[2, 1:] == 0).all()
+    assert int(counts[pa.EV_TOTAL]) == (1 if detect else 0)
+    assert slot.sum() == (int(slot[2, 0]) if detect else 0)
+    ref_out, ref_slot = ref.paged_prefill_ref(
+        q, k_pages, v_pages, bt, q_start, layer=0,
+        detector_k=det, detector_v=det,
+    )
+    np.testing.assert_array_equal(slot, np.asarray(ref_slot))
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32)[live_rows],
+        np.asarray(ref_out, np.float32)[live_rows],
+        atol=tol, rtol=tol,
     )
 
 
@@ -618,6 +729,39 @@ def test_decode_walk_counters(model_params):
     assert m["decode_pages_walked"] == want["pages"]
     assert m["decode_page_slots"] == want["steps"] * 2 * 8
     assert 0 < m["decode_pages_walked"] < m["decode_page_slots"]
+
+
+def test_prefill_walk_counters(model_params):
+    """``prefill_pages_walked`` sums, over the fused prefill chunks, the
+    slots the kernel's live rule reads (first position at or before the
+    chunk's last, padded row — the oracle's ``_live_slots``);
+    ``prefill_page_slots`` is chunks x M — counted on the host from the
+    chunk start, the chunk width and M."""
+    model, params = model_params
+    eng = Engine(model, params, ServingConfig(
+        page_size=4, n_pages=16, max_batch=2, max_pages_per_request=8,
+        prefill_chunk=3,
+    ))
+    prompt = jax.random.randint(jax.random.PRNGKey(9), (14,), 1, 96)
+    eng.add_request(prompt, max_new=4)
+    eng.add_request([4, 17, 2, 8], max_new=4)
+    want = {"pages": 0, "chunks": 0}
+    fn = eng._prefill_fn
+
+    def spy(params, tree, batch, bt, q_start, q_len, stream):
+        C, M = batch["tokens"].shape[1], bt.shape[1]
+        live = ref._live_slots(M, 4, jnp.asarray(q_start) + C - 1)
+        want["pages"] += int(live.sum())
+        want["chunks"] += 1
+        return fn(params, tree, batch, bt, q_start, q_len, stream)
+
+    eng._prefill_fn = spy
+    eng.run()
+    m = eng.metrics()
+    assert want["chunks"] == 5 + 2                     # 14 and 4 tokens, 3 a chunk
+    assert m["prefill_pages_walked"] == want["pages"]
+    assert m["prefill_page_slots"] == want["chunks"] * 8
+    assert 0 < m["prefill_pages_walked"] < m["prefill_page_slots"]
 
 
 def test_fatal_pages_probe_is_deprecated(model_params):

@@ -8,8 +8,9 @@ at qwen2-1.5b attention widths (bf16, 12 query / 2 KV heads, head_dim
 the compiled HLO must hold the kernel (``tpu_custom_call``).  The chunked
 prefill and split-K decode also compile at starcoder2-15b's widths (48 /
 4 heads, a 10-layer pool) and the geometry of its benchmark cell: a
-256-row chunk there holds 12,288 (C, H) rows in one grid cell.  The
-four-chip cases compile the shard_map wrappers over a 4x1 mesh of the
+256-row chunk there holds 12,288 (C, H) rows, walked in four row tiles of
+3,072.  A whole 300-token prompt (``prefill_chunk=0``) at qwen2's widths
+compiles padded to two 256-row tiles.  The four-chip cases compile the shard_map wrappers over a 4x1 mesh of the
 described devices.
 
 The topology is described inside a fixture, never at import: only one
@@ -88,7 +89,7 @@ def _pa():
     return importlib.import_module("repro.kernels.paged_attention")
 
 
-def _single_chip_case(name, one_chip, widths="qwen2"):
+def _single_chip_case(name, one_chip, widths="qwen2", chunk=CHUNK):
     """(jitted fn, abstract args) of one single-chip kernel call."""
     pa = _pa()
     scrub = importlib.import_module("repro.kernels.scrub")
@@ -121,7 +122,7 @@ def _single_chip_case(name, one_chip, widths="qwen2"):
             lambda q, k, v, bt, qs, lay: pa.paged_prefill_raw(
                 q, k, v, bt, qs, lay, interpret=False
             ),
-            (s((1, CHUNK, H, DH), BF16), pages, pages, s((1, M), I32),
+            (s((1, chunk, H, DH), BF16), pages, pages, s((1, M), I32),
              s((1,), I32), s((), I32)),
         )
     if name == "scrub":
@@ -145,18 +146,20 @@ def _single_chip_case(name, one_chip, widths="qwen2"):
 
 
 @pytest.mark.parametrize(
-    "name,widths",
+    "name,widths,chunk",
     [
-        pytest.param(name, "qwen2", id=name)
+        pytest.param(name, "qwen2", CHUNK, id=name)
         for name in ("paged_decode", "paged_decode_splitk", "paged_prefill",
                      "scrub", "scrub_pages", "repair_matmul")
     ] + [
-        pytest.param(name, "starcoder2", id=f"{name}-starcoder2")
+        pytest.param(name, "starcoder2", CHUNK, id=f"{name}-starcoder2")
         for name in ("paged_decode_splitk", "paged_prefill")
+    ] + [
+        pytest.param("paged_prefill", "qwen2", 300, id="paged_prefill-padded"),
     ],
 )
-def test_kernel_compiles_for_v5e(name, widths, one_chip):
-    fn, args = _single_chip_case(name, one_chip, widths)
+def test_kernel_compiles_for_v5e(name, widths, chunk, one_chip):
+    fn, args = _single_chip_case(name, one_chip, widths, chunk)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # the kernels stream HBM blocks: no whole-pool temporaries
